@@ -5,8 +5,9 @@ migrate, resume from the tenant checkpoint, then grow the slice
 Run:  PYTHONPATH=src python examples/elastic_failover.py
 """
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":      # simulate an 8-chip pod
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import tempfile                                   # noqa: E402
 import numpy as np                                # noqa: E402
@@ -23,9 +24,10 @@ from repro.launch.mesh import make_local_mesh     # noqa: E402
 from repro.models import build_model              # noqa: E402
 
 ARCH = "internlm2-1.8b"
-mesh = make_local_mesh((2, 4))
+half = len(jax.devices()) // 2                    # 4 on the CPU, 2 on 4 chips
+mesh = make_local_mesh((2, half))
 vmm = VMM(mesh, policy="hybrid", ckpt_root=tempfile.mkdtemp())
-tenant = vmm.create_vm("trainer", (1, 4))
+tenant = vmm.create_vm("trainer", (1, half))
 tenant.device.open()
 
 cfg = get_config(ARCH, reduced=True)
@@ -58,7 +60,7 @@ print(f"[failure] slice marked failed, events={events}")
 # migrate to a fresh equal slice; state restored from checkpoint
 template = {"params": jax.tree.map(jnp.zeros_like, params),
             "opt": jax.tree.map(jnp.zeros_like, opt_state)}
-vmm.migrate_tenant(tenant, new_shape=(1, 4), state_template=template)
+vmm.migrate_tenant(tenant, new_shape=(1, half), state_template=template)
 params, opt_state = tenant.state["params"], tenant.state["opt"]
 print(f"[migrated] now on slice {tenant.vslice.spec.origin} "
       f"(healthy={tenant.vslice.healthy})")
@@ -68,9 +70,9 @@ for step in range(6, 12):
     params, opt_state, m = tenant.device.run(params, opt_state, batch)
 print(f"[phase2] resumed, loss={float(m['loss']):.4f}")
 
-# elastic grow: 4 → 8 chips
+# elastic grow: the whole pod
 tenant.state = {"params": params, "opt": opt_state}
-elastic.resize(vmm, tenant, (2, 4), state_template=template)
+elastic.resize(vmm, tenant, (2, half), state_template=template)
 params, opt_state = tenant.state["params"], tenant.state["opt"]
 print(f"[elastic] grown to {tenant.vslice.spec.shape} = "
       f"{tenant.vslice.n_devices} chips")

@@ -1,7 +1,8 @@
 """Multi-tenant serving — the paper's Figure-2 cloud scenario.
 
-An 8-device "pod" (host-platform devices) is floorplanned into two
-vSlices; two tenants serve different architectures concurrently, each
+A pod of N devices (four TPU chips, or eight host-platform devices on
+the CPU) is floorplanned as a (2, N/2) mesh into two (1, N/2) vSlices;
+two tenants serve different architectures concurrently, each
 through its own GuestDevice, with the data plane mediated by the
 weighted-fair-queueing scheduler (alice weight 3, bob weight 1) and the
 decode loops driven through the async ``run_async`` futures API.
@@ -14,8 +15,9 @@ Run:  PYTHONPATH=src python examples/multi_tenant_serving.py
       # bob batch traffic — stats report per-tenant SLO attainment
 """
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":      # simulate an 8-chip pod
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import argparse                                   # noqa: E402
 import tempfile                                   # noqa: E402
@@ -35,18 +37,20 @@ ap.add_argument("--metrics", action="store_true",
                      "Prometheus exposition at exit")
 cli = ap.parse_args()
 
-mesh = make_local_mesh((2, 4))
+half = len(jax.devices()) // 2
+mesh = make_local_mesh((2, half))
 vmm = VMM(mesh, policy=cli.policy, ckpt_root=tempfile.mkdtemp(),
           obs=ObsHub(enabled=cli.metrics))
 
 if cli.policy == "slo":
     # deadline classes instead of weights: alice is latency-sensitive
-    alice = vmm.create_vm("alice", (1, 4), sched_priority=PRIORITY_HIGH,
+    alice = vmm.create_vm("alice", (1, half),
+                          sched_priority=PRIORITY_HIGH,
                           sched_slo_wait_s=0.05)
-    bob = vmm.create_vm("bob", (1, 4))
+    bob = vmm.create_vm("bob", (1, half))
 else:
-    alice = vmm.create_vm("alice", (1, 4), sched_weight=3.0)
-    bob = vmm.create_vm("bob", (1, 4), sched_weight=1.0)
+    alice = vmm.create_vm("alice", (1, half), sched_weight=3.0)
+    bob = vmm.create_vm("bob", (1, half), sched_weight=1.0)
 print("floorplan:", vmm.floorplanner.snapshot())
 
 for tenant, arch in ((alice, "qwen1.5-0.5b"), (bob, "internlm2-1.8b")):

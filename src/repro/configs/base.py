@@ -134,14 +134,6 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     opt_dtype: str = "float32"
 
-    # Kernels ----------------------------------------------------------------
-    # Swap the XLA hot-spot paths for the Pallas TPU kernels (kernels/):
-    # flash_attention (self-attn fwd), decode_attention, rglru_scan,
-    # rwkv6_wkv. Off by default: the dry-run lowers on the CPU backend
-    # where Pallas runs in interpret mode (correct but slow) — flip on for
-    # real TPU deployments. Parity pinned in tests/test_kernel_integration.py.
-    use_pallas: bool = False
-
     sharding: ShardingProfile = field(default_factory=ShardingProfile)
 
     # citation / provenance ----------------------------------------------------

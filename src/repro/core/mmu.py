@@ -56,7 +56,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 SEGMENT_BYTES = 16 * 2 ** 20          # 16 MiB
-HBM_PER_CHIP = 16 * 2 ** 30           # v5e: 16 GB
+HBM_PER_CHIP = 16 * 2 ** 30           # a CPU-simulated chip (v5e size)
 
 #: PageTable entry sentinel: the logical block is swapped out to the
 #: host tier — it has no physical frame until ``swap_in_page``.
@@ -65,6 +65,23 @@ SWAPPED = -1
 
 class MMUError(Exception):
     pass
+
+
+def device_hbm_bytes(devices) -> int:
+    """Memory per chip that a tenant pool may lease: the smallest
+    ``memory_stats()["bytes_limit"]`` over ``devices``. CPU devices
+    stand in for chips of ``HBM_PER_CHIP``; an accelerator that reports
+    no limit is an error, never a guess."""
+    limits = []
+    for d in devices:
+        if d.platform == "cpu":
+            limits.append(HBM_PER_CHIP)
+            continue
+        limit = (d.memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            raise MMUError(f"{d} reports no memory_stats bytes_limit")
+        limits.append(int(limit))
+    return min(limits)
 
 
 class IsolationViolation(MMUError):

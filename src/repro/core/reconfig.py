@@ -17,6 +17,7 @@ onto a vSlice. The mapping (DESIGN.md §2):
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import threading
 import time
@@ -108,10 +109,11 @@ class LoadedProgram:
 class CompileService:
     """AOT lower+compile against a slice mesh, with an executable cache.
 
-    Cache key = (program_key, topology_key): a program compiled once for a
-    2×4 slice is a warm hit for *any* 2×4 slice (the paper's observation
-    that PR bitfiles are only shell/region-compatible, made less painful
-    by topology-class reuse)."""
+    Cache key = (program_key, slice fingerprint): an XLA executable is
+    bound to the devices it was compiled for, so a warm hit is the same
+    program re-flashed onto the same slice. Another slice of the same
+    topology class compiles its own: handing it the first slice's
+    executable would run the tenant on its neighbour's chips."""
 
     def __init__(self, step_builder: Optional[Callable] = None):
         # step_builder(cfg, mesh, cell) → (jitted, abstract_args)
@@ -125,14 +127,13 @@ class CompileService:
         self._lock = threading.Lock()
 
     def compile(self, req: ProgramRequest, vslice: VSlice) -> Bitfile:
-        key = (req.program_key, vslice.topology_key)
+        key = (req.program_key, vslice.fingerprint)
         with self._lock:
             if key in self.cache:
                 self.hits += 1
                 cached = self.cache[key]
-                # re-bind to this concrete slice (warm reconfig)
                 return Bitfile(cached.program_key, cached.topology_key,
-                               vslice.fingerprint, cached.compiled,
+                               cached.slice_fingerprint, cached.compiled,
                                cached.abstract_args,
                                compile_seconds=0.0)
         from repro.configs import get_config
@@ -142,8 +143,8 @@ class CompileService:
                          req.kind)
         t0 = time.perf_counter()
         mesh = getattr(vslice, "mesh", None)
-        from repro.compat import set_mesh_ctx
-        ctx = set_mesh_ctx(mesh)
+        ctx = (jax.set_mesh(mesh) if mesh is not None
+               else contextlib.nullcontext())
         with ctx:
             jitted, abstract_args = self._build(cfg, mesh, cell)
             lowered = jitted.lower(*abstract_args)

@@ -29,6 +29,8 @@ delegated to the selected ``DataPlane``.
 """
 from __future__ import annotations
 
+import os
+import tempfile
 import threading
 from typing import Dict, Optional, Tuple
 
@@ -56,9 +58,9 @@ class VMM:
     def __init__(self, pod_mesh, policy: str = "hybrid",
                  mmu_backend: str = "bitmap",
                  transfer_mode: str = "vm_copy",
-                 hbm_per_chip: int = mmu_mod.HBM_PER_CHIP,
+                 hbm_per_chip: Optional[int] = None,
                  segment_bytes: int = mmu_mod.SEGMENT_BYTES,
-                 ckpt_root: str = "/tmp/vpod_ckpt",
+                 ckpt_root: Optional[str] = None,
                  straggler_factor: float = 4.0,
                  oplog_sampling: float = 1.0,
                  scheduler_opts: Optional[dict] = None,
@@ -66,7 +68,9 @@ class VMM:
         assert policy in POLICIES
         self.policy = policy
         self.mmu_backend = mmu_backend
-        self.hbm_per_chip = hbm_per_chip
+        # per-chip pool capacity: what the devices report unless given
+        self.hbm_per_chip = hbm_per_chip or mmu_mod.device_hbm_bytes(
+            np.asarray(pod_mesh.devices).flat)
         self.segment_bytes = segment_bytes
         # Telemetry plane (repro.obs): every subsystem below reports
         # into this hub's registry/tracer/flight recorder. Disabled by
@@ -80,7 +84,8 @@ class VMM:
         self.transfer = TransferEngine(mode=transfer_mode, obs=self.obs)
         self.compiler = CompileService()
         self.loader = ProgramLoader(auditor=self.auditor)
-        self.checkpointer = TenantCheckpointer(ckpt_root)
+        self.checkpointer = TenantCheckpointer(
+            ckpt_root or os.path.join(tempfile.gettempdir(), "vpod_ckpt"))
         self.tenants: Dict[str, Tenant] = {}   # guarded-by: _lock
         self._lock = threading.Lock()
         # Data-plane dispatch is fully delegated to the scheduler subsystem.

@@ -135,11 +135,21 @@ def decode_attention(q, k, v, pos, *, window=0, interpret=False, bkv=BKV):
 # ===========================================================================
 # Paged variant: block-table walk over a shared physical page pool
 # ===========================================================================
+#
+# One grid step reads one whole page, all KV heads of it: a
+# (page_size, Hkv, hd) block whose last two dims are the pool's own, which
+# is the block shape the TPU compiler accepts (a single-head (ps, 1, hd)
+# block is refused). The query heads of each KV group are then handled
+# together, as G rows of one (G, ps) score tile.
 
 
-def _paged_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, scale, ps, nb, window, hq):
-    g = pl.program_id(0)                              # b * Hq + h
+def _paged_kernel(len_ref, bt_ref, q_ref, *refs, scale, ps, nb, window,
+                  hkv, g, fused):
+    if fused:
+        kn_ref, vn_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    b = pl.program_id(0)
     j = pl.program_id(1)                              # logical block index
 
     @pl.when(j == 0)
@@ -148,33 +158,81 @@ def _paged_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0]                                   # (1, hd)
-    k = k_ref[0, :, 0]                                # (ps, hd)
-    v = v_ref[0, :, 0]
-    length = len_ref[g // hq]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    length = len_ref[b]                   # fused: includes the new token
     tok = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
     valid = tok < length                              # linear, no ring
     if window > 0:
         valid &= tok >= length - window
-    s = jnp.where(valid, s, _NEG)
-    m_prev = m_ref[:1, :1]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(valid, p, 0.0)
-    l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    if fused:
+        # the new token lives at logical index length-1 but is NOT in the
+        # pool yet: substitute its VMEM-resident row into the sweep
+        is_new = (j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
+                  == length - 1)
+    for h in range(hkv):
+        rows = slice(h * g, (h + 1) * g)
+        q = q_ref[0, rows, :]                         # (G, hd)
+        k = k_ref[0, :, h, :]                         # (ps, hd)
+        v = v_ref[0, :, h, :]
+        if fused:
+            k = jnp.where(is_new, kn_ref[0, h:h + 1, :], k)
+            v = jnp.where(is_new, vn_ref[0, h:h + 1, :], v)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid, s, _NEG)                 # (G, ps)
+        m_prev = m_ref[rows, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[rows, :] = l_ref[rows, :] * alpha + p.sum(-1, keepdims=True)
+        acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[rows, :] = jnp.broadcast_to(m_new, (g, m_ref.shape[1]))
 
     @pl.when(j == nb - 1)
     def _flush():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[:1, :1], 1e-30)).astype(
-                           o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_call(q, new_kv, k_pages, v_pages, lengths, block_tables, *,
+                window, interpret):
+    """Shared pallas_call of the paged and fused kernels. ``new_kv`` is
+    ``(k_new, v_new)`` (B, Hkv, 1, hd) for the fused step, else None."""
+    B, Hq, _, hd = q.shape
+    _, ps, Hkv, _ = k_pages.shape
+    nb = block_tables.shape[1]
+    fused = new_kv is not None
+    head_spec = lambda h: pl.BlockSpec((1, h, hd),
+                                       lambda b, j, lens, bt: (b, 0, 0))
+    page_spec = pl.BlockSpec((1, ps, Hkv, hd),
+                             lambda b, j, lens, bt: (bt[b, j], 0, 0, 0))
+    in_specs = [head_spec(Hq)]
+    operands = [q[:, :, 0]]
+    if fused:
+        in_specs += [head_spec(Hkv), head_spec(Hkv)]
+        operands += [x[:, :, 0] for x in new_kv]
+    in_specs += [page_spec, page_spec]
+    operands += [k_pages, v_pages]
+    kernel = functools.partial(_paged_kernel, scale=hd ** -0.5, ps=ps,
+                               nb=nb, window=window, hkv=Hkv, g=Hq // Hkv,
+                               fused=fused)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, nb),
+        in_specs=in_specs,
+        out_specs=head_spec(Hq),
+        scratch_shapes=[pltpu.VMEM((Hq, hd), jnp.float32),
+                        pltpu.VMEM((Hq, 128), jnp.float32),
+                        pltpu.VMEM((Hq, 128), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), *operands)
+    return out[:, :, None]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -184,94 +242,13 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, block_tables, *,
     lengths: (B,) int32 valid-token counts (0 = dead slot → zero out);
     block_tables: (B, nb) int32 logical block → physical page (pad with
     any in-range page; padded entries are masked by ``lengths``)."""
-    B, Hq, _, hd = q.shape
-    _, ps, Hkv, _ = k_pages.shape
-    G = Hq // Hkv
-    nb = block_tables.shape[1]
-    grid = (B * Hq, nb)
-
-    kernel = functools.partial(_paged_kernel, scale=hd ** -0.5, ps=ps,
-                               nb=nb, window=window, hq=Hq)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda g, j, lens, bt: (g // Hq, g % Hq, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda g, j, lens, bt:
-                         (bt[g // Hq, j], 0, (g % Hq) // G, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda g, j, lens, bt:
-                         (bt[g // Hq, j], 0, (g % Hq) // G, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd),
-                               lambda g, j, lens, bt:
-                               (g // Hq, g % Hq, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((1, hd), jnp.float32),
-                        pltpu.VMEM((1, 128), jnp.float32),
-                        pltpu.VMEM((1, 128), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_pages, v_pages)
+    return _paged_call(q, None, k_pages, v_pages, lengths, block_tables,
+                       window=window, interpret=interpret)
 
 
 # ===========================================================================
 # Fused serving step: new-token KV in-register + paged sweep
 # ===========================================================================
-
-
-def _fused_kernel(len_ref, bt_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
-                  o_ref, acc_ref, m_ref, l_ref, *, scale, ps, nb, window,
-                  hq):
-    g = pl.program_id(0)                              # b * Hq + h
-    j = pl.program_id(1)                              # logical block index
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0]                                   # (1, hd)
-    k = k_ref[0, :, 0]                                # (ps, hd)
-    v = v_ref[0, :, 0]
-    kn = kn_ref[0, 0]                                 # (1, hd) new token
-    vn = vn_ref[0, 0]
-    length = len_ref[g // hq]                         # includes new token
-    tok = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    # the new token lives at logical index length-1 but is NOT in the
-    # pool yet — substitute its VMEM-resident row into the sweep
-    is_new = (tok == length - 1).reshape(ps, 1)
-    k_eff = jnp.where(is_new, kn, k)
-    v_eff = jnp.where(is_new, vn, v)
-    s = jax.lax.dot_general(q, k_eff, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    valid = tok < length
-    if window > 0:
-        valid &= tok >= length - window
-    s = jnp.where(valid, s, _NEG)
-    m_prev = m_ref[:1, :1]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(valid, p, 0.0)
-    l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(v_eff.dtype), v_eff, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(j == nb - 1)
-    def _flush():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[:1, :1], 1e-30)).astype(
-                           o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -288,47 +265,8 @@ def fused_paged_decode_attention(q, k_new, v_new, k_pages, v_pages,
     block_tables: (B, nb) int32. The caller persists k_new/v_new to the
     pool separately — this kernel never reads the page being written.
     """
-    B, Hq, _, hd = q.shape
-    _, ps, Hkv, _ = k_pages.shape
-    G = Hq // Hkv
-    nb = block_tables.shape[1]
-    grid = (B * Hq, nb)
-
-    kernel = functools.partial(_fused_kernel, scale=hd ** -0.5, ps=ps,
-                               nb=nb, window=window, hq=Hq)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda g, j, lens, bt: (g // Hq, g % Hq, 0, 0)),
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda g, j, lens, bt:
-                         (g // Hq, (g % Hq) // G, 0, 0)),
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda g, j, lens, bt:
-                         (g // Hq, (g % Hq) // G, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda g, j, lens, bt:
-                         (bt[g // Hq, j], 0, (g % Hq) // G, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda g, j, lens, bt:
-                         (bt[g // Hq, j], 0, (g % Hq) // G, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd),
-                               lambda g, j, lens, bt:
-                               (g // Hq, g % Hq, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((1, hd), jnp.float32),
-                        pltpu.VMEM((1, 128), jnp.float32),
-                        pltpu.VMEM((1, 128), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_new, v_new, k_pages, v_pages)
+    return _paged_call(q, (k_new, v_new), k_pages, v_pages, lengths,
+                       block_tables, window=window, interpret=interpret)
 
 
 # ===========================================================================
@@ -338,7 +276,6 @@ def fused_paged_decode_attention(q, k_new, v_new, k_pages, v_pages,
 
 def _sample_kernel(temp_ref, s_ref, n_ref, tok_ref, m_ref, i_ref, *,
                    bv, nv):
-    b = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -349,9 +286,9 @@ def _sample_kernel(temp_ref, s_ref, n_ref, tok_ref, m_ref, i_ref, *,
     # argmax(logits + g·T): Gumbel-max softmax sampling at temperature T
     # (argmax is scale-invariant: argmax(l/T + g) == argmax(l + g·T)),
     # greedy argmax at T = 0 — one formula for both
-    s = s_ref[0] + n_ref[0] * temp_ref[b]             # (1, bv)
-    bmax = s.max(axis=-1, keepdims=True)              # (1, 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, bv), 1)
+    s = s_ref[...] + n_ref[...] * temp_ref[...]       # (tb, bv)
+    bmax = s.max(axis=-1, keepdims=True)              # (tb, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     # first column attaining the block max (matches np.argmax ties)
     bidx = jnp.min(jnp.where(s == bmax, col, bv),
                    axis=-1, keepdims=True) + j * bv
@@ -365,33 +302,33 @@ def _sample_kernel(temp_ref, s_ref, n_ref, tok_ref, m_ref, i_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bv"))
-def sample_tokens(logits, temps, noise, *, interpret=False, bv=None):
+def sample_tokens(logits, temps, noise, *, interpret=False, bv=2048):
     """logits (B, V) fp32; temps (B,) fp32 (0 = greedy); noise (B, V)
-    Gumbel draws (ignored where temps == 0). → (B,) int32 token ids."""
+    Gumbel draws (ignored where temps == 0). → (B,) int32 token ids.
+
+    Rows go in tiles of 8 (or all B when B is not a multiple of 8), the
+    vocabulary in ``bv``-wide blocks; a vocabulary that is not a multiple
+    of ``bv`` is padded with -inf logits, which never win."""
     B, V = logits.shape
-    if bv is None:
-        bv = min(V, 2048)
-    while V % bv:
-        bv //= 2
-    nv = V // bv
-    grid = (B, nv)
+    bv = min(bv, V)
+    tb = 8 if B % 8 == 0 else B
+    logits = logits.astype(jnp.float32)
+    noise = noise.astype(jnp.float32)
+    pad = -V % bv
+    if pad:
+        logits = jnp.pad(logits, ((0, 0), (0, pad)), constant_values=_NEG)
+        noise = jnp.pad(noise, ((0, 0), (0, pad)))
+    nv = (V + pad) // bv
     kernel = functools.partial(_sample_kernel, bv=bv, nv=nv)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bv), lambda b, j, t: (b, j)),
-            pl.BlockSpec((1, bv), lambda b, j, t: (b, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, j, t: (b, 0)),
-        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.int32)],
-    )
-    out = pl.pallas_call(
+    row_spec = pl.BlockSpec((tb, 1), lambda i, j: (i, 0))
+    vocab_spec = pl.BlockSpec((tb, bv), lambda i, j: (i, j))
+    return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid=(B // tb, nv),
+        in_specs=[row_spec, vocab_spec, vocab_spec],
+        out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((tb, 1), jnp.float32),
+                        pltpu.VMEM((tb, 1), jnp.int32)],
         interpret=interpret,
-    )(temps.astype(jnp.float32), logits.astype(jnp.float32),
-      noise.astype(jnp.float32))
-    return out[:, 0]
+    )(temps.astype(jnp.float32)[:, None], logits, noise)[:, 0]

@@ -8,7 +8,7 @@ per-slot ``lengths`` vector (B,).
 """
 import jax.numpy as jnp
 
-from repro.kernels.common import use_interpret
+from repro.kernels import common
 from repro.kernels.decode_attention.decode_attention import (
     BKV, decode_attention, fused_paged_decode_attention,
     paged_decode_attention, sample_tokens)
@@ -26,7 +26,7 @@ def decode_attention_op(q, k_cache, v_cache, pos, *, window=0,
     if block_tables is not None:
         out = paged_decode_attention(
             qt, k_cache, v_cache, jnp.asarray(pos, jnp.int32),
-            block_tables, window=window, interpret=use_interpret())
+            block_tables, window=window, interpret=common.use_interpret())
         return out.transpose(0, 2, 1, 3)
     kt = k_cache.transpose(0, 2, 1, 3)
     vt = v_cache.transpose(0, 2, 1, 3)
@@ -35,7 +35,7 @@ def decode_attention_op(q, k_cache, v_cache, pos, *, window=0,
     while C % bkv:
         bkv //= 2
     out = decode_attention(qt, kt, vt, jnp.asarray(pos, jnp.int32),
-                           window=window, interpret=use_interpret(),
+                           window=window, interpret=common.use_interpret(),
                            bkv=max(bkv, 1))
     return out.transpose(0, 2, 1, 3)
 
@@ -53,7 +53,7 @@ def fused_decode_step_op(q, k_new, v_new, k_pages, v_pages, lengths,
     out = fused_paged_decode_attention(
         qt, k_new.transpose(0, 2, 1, 3), v_new.transpose(0, 2, 1, 3),
         k_pages, v_pages, jnp.asarray(lengths, jnp.int32), block_tables,
-        window=window, interpret=use_interpret())
+        window=window, interpret=common.use_interpret())
     return out.transpose(0, 2, 1, 3)
 
 
@@ -88,7 +88,8 @@ def fused_paged_attention_xla(q, k_new, v_new, k_pages, v_pages, lengths,
 
 def sample_tokens_op(logits, temps, noise):
     """On-device argmax/Gumbel-max sampling: (B,V)+(B,)+(B,V) → (B,)."""
-    return sample_tokens(logits, temps, noise, interpret=use_interpret())
+    return sample_tokens(logits, temps, noise,
+                         interpret=common.use_interpret())
 
 
 def sample_tokens_xla(logits, temps, noise):

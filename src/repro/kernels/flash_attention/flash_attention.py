@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 BQ, BK = 512, 512
 _NEG = -1e30
 
@@ -105,7 +103,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, interpret=False,
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

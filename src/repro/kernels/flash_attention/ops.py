@@ -7,7 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import round_up, use_interpret
+from repro.kernels import common
+from repro.kernels.common import round_up
 from repro.kernels.flash_attention.flash_attention import (BK, BQ,
                                                            flash_attention)
 from repro.kernels.flash_attention.ref import flash_attention_ref
@@ -16,7 +17,7 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _fa(q, k, v, causal, window):
     return flash_attention(q, k, v, causal=causal, window=window,
-                           interpret=use_interpret(),
+                           interpret=common.use_interpret(),
                            bq=min(BQ, q.shape[2]), bk=min(BK, k.shape[2]))
 
 

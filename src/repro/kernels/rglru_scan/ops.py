@@ -1,7 +1,8 @@
 """Public wrapper with padding + auto-interpret."""
 import jax.numpy as jnp
 
-from repro.kernels.common import round_up, use_interpret
+from repro.kernels import common
+from repro.kernels.common import round_up
 from repro.kernels.rglru_scan.rglru_scan import BD, BS, rglru_scan
 
 
@@ -15,5 +16,5 @@ def rglru_scan_op(a, b, h0):
                     constant_values=1.0)
         b = jnp.pad(b, ((0, 0), (0, sp - S), (0, dp - D)))
         h0 = jnp.pad(h0, ((0, 0), (0, dp - D)))
-    out = rglru_scan(a, b, h0, interpret=use_interpret(), bs=bs, bd=bd)
+    out = rglru_scan(a, b, h0, interpret=common.use_interpret(), bs=bs, bd=bd)
     return out[:, :S, :D]

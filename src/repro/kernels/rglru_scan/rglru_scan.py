@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 BS, BD = 256, 512
 
 
@@ -27,7 +25,7 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, h_ref, *, bs, ns):
 
     @pl.when(sidx == 0)
     def _init():
-        h_ref[...] = h0_ref[...]                     # (1, bd)
+        h_ref[...] = h0_ref[0]                       # (1, bd)
 
     def body(t, h):
         a_t = a_ref[0, pl.ds(t, 1), :]               # (1, bd)
@@ -52,11 +50,11 @@ def rglru_scan(a, b, h0, *, interpret=False, bs=BS, bd=BD):
         grid=(B, D // bd, ns),
         in_specs=[pl.BlockSpec((1, bs, bd), lambda i, j, s: (i, s, j)),
                   pl.BlockSpec((1, bs, bd), lambda i, j, s: (i, s, j)),
-                  pl.BlockSpec((1, bd), lambda i, j, s: (i, j))],
+                  pl.BlockSpec((1, 1, bd), lambda i, j, s: (i, 0, j))],
         out_specs=pl.BlockSpec((1, bs, bd), lambda i, j, s: (i, s, j)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a, b, h0)
+    )(a, b, h0[:, None])

@@ -1,7 +1,8 @@
 """Public wrapper with sequence padding + auto-interpret."""
 import jax.numpy as jnp
 
-from repro.kernels.common import round_up, use_interpret
+from repro.kernels import common
+from repro.kernels.common import round_up
 from repro.kernels.rwkv6_wkv.rwkv6_wkv import CHUNK, rwkv6_wkv
 
 
@@ -14,5 +15,5 @@ def rwkv6_wkv_op(r, k, v, logw, u, s0, chunk=CHUNK):
         # k=r=0, logw=0 → padded steps change nothing
         r, k, v, logw = (jnp.pad(t, pad) for t in (r, k, v, logw))
     o, s_fin = rwkv6_wkv(r, k, v, logw, u, s0,
-                         interpret=use_interpret(), chunk=c)
+                         interpret=common.use_interpret(), chunk=c)
     return o[:, :, :S], s_fin

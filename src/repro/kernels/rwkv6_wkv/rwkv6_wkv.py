@@ -8,7 +8,7 @@ so the kernel is numerically safe at any decay rate — the property that
 lets the chunk size be a VMEM-tiling choice rather than a numerics one.
 
 Grid: (B·H, S/C) — batch×head parallel, chunks sequential. Per-chunk work
-is three (C×K)·(K×V) MXU dots + one (C,C,K) VPU elementwise block.
+is three (C×K)·(K×V) MXU dots + C (C,K) VPU elementwise tiles.
 """
 from __future__ import annotations
 
@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.compat import tpu_compiler_params
 
 CHUNK = 32
 
@@ -38,7 +36,14 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sf_ref,
     lw = lw_ref[0, 0]                                # (c, K) ≤ 0
     u = u_ref[0]                                     # (1, K)
 
-    L = jnp.cumsum(lw, axis=0)                       # inclusive
+    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum); full f32 precision, the decays are exponentiated below
+    L = jax.lax.dot_general((ii >= jj).astype(jnp.float32), lw,
+                            (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     Lp = L - lw                                      # exclusive
     s = s_ref[...]
 
@@ -46,15 +51,13 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sf_ref,
     o = jax.lax.dot_general(r * jnp.exp(Lp), s, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (c, V)
 
-    # intra-chunk: pairwise per-channel decays, log-space safe
-    diff = Lp[:, None, :] - L[None, :, :]            # (c, c, K)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    causal = (ii > jj)[:, :, None]
-    D = jnp.where(causal, jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
-    scores = (r[:, None, :] * k[None, :, :] * D).sum(-1)          # (c, c)
-    o = o + jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    # intra-chunk: pairwise per-channel decays, log-space safe, one key
+    # row j at a time (2-D tiles only: Mosaic has no (c, c, K) broadcast)
+    later = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    for j in range(c - 1):
+        decay = jnp.exp(jnp.minimum(Lp - L[j:j + 1], 0.0))          # (c, K)
+        score = (r * k[j:j + 1] * decay).sum(-1, keepdims=True)     # (c, 1)
+        o = o + jnp.where(later > j, score, 0.0) * v[j:j + 1]
     bonus = (r * u * k).sum(-1, keepdims=True)                    # (c, 1)
     o = o + bonus * v
     o_ref[0, 0] = o.astype(o_ref.dtype)
@@ -83,7 +86,7 @@ def rwkv6_wkv(r, k, v, logw, u, s0, *, interpret=False, chunk=CHUNK):
     nc = S // c
     grid = (B * H, nc)
     io_spec = pl.BlockSpec((1, 1, c, K), lambda g, ci: (g // H, g % H, ci, 0))
-    u_spec = pl.BlockSpec((1, K), lambda g, ci: (g % H, 0))
+    u_spec = pl.BlockSpec((1, 1, K), lambda g, ci: (g % H, 0, 0))
     s_spec = pl.BlockSpec((1, 1, K, K), lambda g, ci: (g // H, g % H, 0, 0))
     return pl.pallas_call(
         functools.partial(_kernel, nc=nc, c=c),
@@ -93,7 +96,7 @@ def rwkv6_wkv(r, k, v, logw, u, s0, *, interpret=False, chunk=CHUNK):
         out_shape=(jax.ShapeDtypeStruct((B, H, S, K), jnp.float32),
                    jax.ShapeDtypeStruct((B, H, K, K), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, logw, u, s0)
+    )(r, k, v, logw, u[:, None], s0)

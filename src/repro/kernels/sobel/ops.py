@@ -1,7 +1,8 @@
 """jit'd public wrapper: zero-pads borders + pads to block multiples."""
 import jax.numpy as jnp
 
-from repro.kernels.common import round_up, use_interpret
+from repro.kernels import common
+from repro.kernels.common import round_up
 from repro.kernels.sobel.sobel import BH, BW, sobel
 
 
@@ -10,5 +11,5 @@ def sobel_op(x, bh=BH, bw=BW):
     bh_, bw_ = min(bh, h), min(bw, w)
     hp, wp = round_up(h, bh_), round_up(w, bw_)
     xp = jnp.pad(x, ((1, hp - h + 1), (1, wp - w + 1)))
-    out = sobel(xp, interpret=use_interpret(), bh=bh_, bw=bw_)
+    out = sobel(xp, interpret=common.use_interpret(), bh=bh_, bw=bw_)
     return out[:h, :w]

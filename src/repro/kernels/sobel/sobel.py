@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.compat import element_block_spec
-
 BH, BW = 256, 256
 
 # Gx/Gy Sobel taps
@@ -48,8 +46,8 @@ def sobel(x_padded, *, interpret=False, bh=BH, bw=BW):
     return pl.pallas_call(
         _kernel,
         grid=(h // bh, w // bw),
-        in_specs=[element_block_spec(
-            (bh + 2, bw + 2),                           # overlapping halo
+        in_specs=[pl.BlockSpec(
+            (pl.Element(bh + 2), pl.Element(bw + 2)),   # overlapping halo
             lambda i, j: (i * bh, j * bw))],            # element offsets
         out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), x_padded.dtype),

@@ -1,7 +1,8 @@
 """jit'd public wrapper: auto-interpret off-TPU, pads to block multiple."""
 import jax.numpy as jnp
 
-from repro.kernels.common import round_up, use_interpret
+from repro.kernels import common
+from repro.kernels.common import round_up
 from repro.kernels.vecadd.vecadd import BLOCK, vecadd
 
 
@@ -11,5 +12,5 @@ def vecadd_op(x, y, block=BLOCK):
     if np_ != n:
         x = jnp.pad(x, (0, np_ - n))
         y = jnp.pad(y, (0, np_ - n))
-    out = vecadd(x, y, interpret=use_interpret(), block=block)
+    out = vecadd(x, y, interpret=common.use_interpret(), block=block)
     return out[:n]

@@ -12,7 +12,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256-chip v5e pod; multi-pod = 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axis types: the partitioner propagates
+    shardings through ops such as the embedding gather, which Explicit
+    axes (``jax.make_mesh``'s default) would reject."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto, devices=devices)
 
 
 def make_local_mesh(shape=None, axes=("data", "model")):
@@ -21,5 +29,6 @@ def make_local_mesh(shape=None, axes=("data", "model")):
     n = len(jax.devices())
     if shape is None:
         shape = (1, n)
-    assert int(np.prod(shape)) <= n, (shape, n)
-    return jax.make_mesh(shape, axes)
+    size = int(np.prod(shape))
+    assert size <= n, (shape, n)
+    return _auto_mesh(shape, axes, jax.devices()[:size])

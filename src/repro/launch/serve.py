@@ -24,7 +24,9 @@ import jax
 import numpy as np
 
 
-def main():
+def main(argv=None):
+    """Serve the requests ``argv`` describes; returns the engine, the
+    finished requests and the wall time, for callers that check them."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--requests", type=int, default=8)
@@ -68,18 +70,20 @@ def main():
                     help="enable the telemetry plane (request spans, "
                          "unified metrics registry, flight recorder); "
                          "prints the Prometheus exposition at exit")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import build_model
     from repro.obs import ObsHub
     from repro.serving import ServeEngine
 
+    enable_compile_cache()
     obs = ObsHub(enabled=args.metrics)
 
     if args.models:
         _serve_mux(args, obs)
-        return
+        return None
 
     cfg = get_config(args.arch, reduced=not args.full)
     model = build_model(cfg)
@@ -156,18 +160,18 @@ def main():
                       temperature=0.0 if i % 2 == 0 else 0.8)
 
     t0 = time.perf_counter()
-    done = 0
+    finished = []
     new_tokens = 0
     while engine.has_work():
         for r in engine.step(params):
-            done += 1
+            finished.append(r)
             new_tokens += len(r.out_tokens)
             print(f"[serve] req {r.rid}: prompt {len(r.prompt)} tok → "
                   f"{len(r.out_tokens)} new: {r.out_tokens[:8]}…")
     dt = time.perf_counter() - t0
     s = engine.stats
-    print(f"[serve] {done} requests, {new_tokens} tokens in {dt:.2f}s "
-          f"({new_tokens / max(dt, 1e-9):.1f} tok/s)")
+    print(f"[serve] {len(finished)} requests, {new_tokens} tokens in "
+          f"{dt:.2f}s ({new_tokens / max(dt, 1e-9):.1f} tok/s)")
     print(f"[serve] engine: {s.steps} steps, {s.prefills} newcomer "
           f"prefills (full={s.full_prefills}, "
           f"chunks={s.prefill_chunks}), {s.page_faults} page "
@@ -198,6 +202,8 @@ def main():
     if args.virtualized:
         print("[serve] vmm stats:", vmm.stats())
         vmm.shutdown()
+    return {"engine": engine, "model": model, "params": params,
+            "finished": finished, "seconds": dt}
 
 
 def _serve_mux(args, obs):

@@ -15,6 +15,8 @@ mediated control plane), with periodic tenant checkpoints (interposition).
 from __future__ import annotations
 
 import argparse
+import os
+import tempfile
 import time
 
 import jax
@@ -22,7 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def main():
+def main(argv=None):
+    """Train as ``argv`` says; returns the loss of each step it ran."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--steps", type=int, default=20)
@@ -33,24 +36,27 @@ def main():
     ap.add_argument("--virtualized", action="store_true")
     ap.add_argument("--policy", default="hybrid",
                     choices=["fev", "bev", "hybrid"])
-    ap.add_argument("--ckpt-dir", default="/tmp/vpod_train_ckpt")
+    ap.add_argument("--ckpt-dir", default=os.path.join(
+        tempfile.gettempdir(), "vpod_train_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--fail-at", type=int, default=0,
                     help="simulate a crash at this step (test restart)")
     ap.add_argument("--micro-steps", type=int, default=1)
     ap.add_argument("--grad-compress", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro import optim
     from repro.checkpointing import CheckpointManager
     from repro.configs import get_config
     from repro.configs.base import ShapeCell
     from repro.data import pipeline_for
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_local_mesh
     from repro.models import build_model
     from repro.parallel import build_train
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=not args.full)
     cell = ShapeCell("cli", args.seq, args.batch, "train")
     mesh = make_local_mesh((1, len(jax.devices())))
@@ -92,6 +98,7 @@ def main():
         run = jitted
 
     t_start = time.perf_counter()
+    losses = []
     for step in range(start_step, args.steps):
         if args.fail_at and step == args.fail_at:
             print(f"[train] simulated failure at step {step} — restart "
@@ -101,6 +108,7 @@ def main():
         t0 = time.perf_counter()
         params, opt_state, metrics = run(params, opt_state, batch)
         loss = float(metrics["loss"])
+        losses.append(loss)
         dt = time.perf_counter() - t0
         if step % 5 == 0 or step == args.steps - 1:
             print(f"[train] step={step:4d} loss={loss:8.4f} "
@@ -118,6 +126,7 @@ def main():
         vmm.checkpoint_tenant(tenant)
         print("[train] vmm stats:", vmm.stats())
         vmm.shutdown()
+    return losses
 
 
 if __name__ == "__main__":

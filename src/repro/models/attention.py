@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
+from repro.kernels.common import use_pallas
 from repro.models.layers import apply_rope, dense_init, dt
 
 _DIRECT_MAX = 2048      # S at or below which the dense path is used
@@ -226,11 +226,12 @@ def attention_core(q, k, v, *, causal, window, q_pos, k_pos):
 
 
 def attn_full(cfg, p, x, *, causal=True, window=0, positions=None,
-              make_cache=False, cache_capacity=0, kv_x=None):
+              make_cache=False, cache_capacity=0, kv_x=None, mesh=None):
     """Self- or cross-attention over a full sequence.
 
     Returns (y, cache|None). Cache layout: {"k","v"}: (B, C, Hkv, hd) ring
-    (slot = pos % C) in compute dtype.
+    (slot = pos % C) in compute dtype. ``mesh`` (a multi-device program's
+    mesh) runs the flash kernel per batch × head shard.
     """
     B, S, _ = x.shape
     q, k, v = _project_qkv(cfg, p, x, kv_x=kv_x)
@@ -240,10 +241,19 @@ def attn_full(cfg, p, x, *, causal=True, window=0, positions=None,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     k_pos = jnp.arange(k.shape[1]) if kv_x is not None else positions
-    if (cfg.use_pallas and kv_x is None and causal
+    if (use_pallas() and kv_x is None and causal
             and q.shape[1] == k.shape[1]):
         from repro.kernels.flash_attention.ops import flash_attention_op
-        y = flash_attention_op(q, k, v, causal=True, window=window)
+
+        def flash(q, k, v):
+            return flash_attention_op(q, k, v, causal=True, window=window)
+        if mesh is not None and mesh.size > 1:
+            # a Mosaic kernel is never auto-partitioned: run it per shard
+            flash = jax.shard_map(flash, mesh=mesh,
+                                  in_specs=(_heads_spec(mesh, q, k),) * 3,
+                                  out_specs=_heads_spec(mesh, q, k),
+                                  check_vma=False)
+        y = flash(q, k, v)
     else:
         y = attention_core(q, k, v, causal=causal and kv_x is None,
                            window=window, q_pos=positions, k_pos=k_pos)
@@ -312,9 +322,20 @@ def attn_decode(cfg, p, x1, cache, pos, *, window=0, mesh=None):
     slot = jnp.mod(pos, C)
     ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
     cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
-    if cfg.use_pallas:
+    if use_pallas():
         from repro.kernels.decode_attention.ops import decode_attention_op
-        o = decode_attention_op(q, ck, cv, pos, window=window)
+
+        def attend(q, ck, cv, pos):
+            return decode_attention_op(q, ck, cv, pos, window=window)
+        if mesh is not None and mesh.size > 1:
+            # a Mosaic kernel is never auto-partitioned: run it per shard
+            # (seq-sharded caches took the split-KV path above)
+            from jax.sharding import PartitionSpec as P
+            spec = _heads_spec(mesh, q, ck)
+            attend = jax.shard_map(attend, mesh=mesh,
+                                   in_specs=(spec, spec, spec, P()),
+                                   out_specs=spec, check_vma=False)
+        o = attend(q, ck, cv, jnp.asarray(pos, jnp.int32))
         return _out_proj(cfg, p, o), {"k": ck, "v": cv}
     scale = 1.0 / np.sqrt(hd)
     Hq = q.shape[2]
@@ -372,7 +393,7 @@ def attn_decode_paged(cfg, p, x1, pools, positions, block_tables, *,
     cv = pools["v"].at[page, off].set(v[:, 0], mode="drop")
     lengths = jnp.maximum(positions + 1, 0)          # dead slot → 0
 
-    if cfg.use_pallas:
+    if use_pallas():
         # fused step: the new token's K/V ride in VMEM and are
         # substituted in-register at index lengths-1, so the sweep reads
         # the *pre-scatter* pools and never waits on the persist-scatter
@@ -437,6 +458,20 @@ def attn_prefill_chunk_paged(cfg, p, x, pools, positions, block_row, *,
     y = attention_core(q, kb, vb, causal=True, window=window,
                        q_pos=positions, k_pos=jnp.arange(S))
     return _out_proj(cfg, p, y), {"k": ck, "v": cv}
+
+
+def _heads_spec(mesh, q, k):
+    """(B, S, H, hd) spec sharding batch over the data axes and heads over
+    ``model``, each only where it divides (q and k heads alike)."""
+    from jax.sharding import PartitionSpec as P
+    names = mesh.axis_names
+    dp = tuple(a for a in ("pod", "data") if a in names)
+    dp_size = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
+    tp = int(mesh.shape["model"]) if "model" in names else 1
+    b = dp if dp and q.shape[0] % dp_size == 0 else None
+    h = ("model" if tp > 1 and q.shape[2] % tp == 0
+         and k.shape[2] % tp == 0 else None)
+    return P(b, None, h, None)
 
 
 def _cache_seq_axes(mesh, B, Hkv):
@@ -514,7 +549,7 @@ def _attn_decode_splitk(cfg, q, k_new, v_new, cache, pos, window, mesh,
     qspec = P(b_axes, None, None, None)
     seq_sh = seq_axes[0] if len(seq_axes) == 1 else tuple(seq_axes)
     cspec = P(b_axes, seq_sh, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(qspec, qspec, qspec, cspec, cspec, P()),
         out_specs=(qspec, cspec, cspec),

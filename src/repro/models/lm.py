@@ -170,7 +170,7 @@ def apply_layer_full(cfg, spec, p, x, ctx, cache=None):
         y, mcache = attn.attn_full(
             cfg, p["mixer"], h, causal=ctx["causal"], window=window,
             positions=ctx.get("positions"), make_cache=mk,
-            cache_capacity=ctx.get("capacity", 0))
+            cache_capacity=ctx.get("capacity", 0), mesh=ctx.get("mesh"))
     elif spec.mixer == "rglru":
         y, mcache = rec.rglru_full(
             cfg, p["mixer"], h,

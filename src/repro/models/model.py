@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.common import use_pallas
 from repro.models import lm
 from repro.models.attention import cross_kv
 from repro.models.layers import (abs_position_vector, add_abs_positions,
@@ -271,7 +272,7 @@ class Model:
         logits = self._lm_logits(params, x[:, -1:])[:, 0]
         key = jax.random.fold_in(jax.random.PRNGKey(0x5e), step)
         noise = jax.random.gumbel(key, logits.shape, jnp.float32)
-        if cfg.use_pallas:
+        if use_pallas():
             from repro.kernels.decode_attention.ops import sample_tokens_op
             toks = sample_tokens_op(logits, temps, noise)
         else:

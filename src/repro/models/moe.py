@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models.layers import dense_init, dt
 
 
@@ -265,7 +264,7 @@ def _apply_moe_inference_2d(cfg, p, x, mesh):
     ed = dp[-1] if dp else None                # d_e sharded over "data"
     shared_spec = (jax.tree.map(lambda _: P(None, None), p["shared"])
                    if "shared" in p else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(xspec, P(None, None), P("model", None, ed),
                   P("model", None, ed), P("model", ed, None), shared_spec),
@@ -314,7 +313,7 @@ def _apply_moe_expert_tp(cfg, p, x, mesh):
     xspec = P(bspec, None, None)
     shared_spec = (jax.tree.map(lambda _: P(None, None), p["shared"])
                    if "shared" in p else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(xspec, P(None, None), P(None, None, "model"),
                   P(None, None, "model"), P(None, "model", None),
@@ -398,7 +397,7 @@ def _apply_moe_token_routing(cfg, p, x, mesh):
                    if "shared" in p else None)
     shared_arg = p.get("shared")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(xspec, P(None, None), wspec, wspec, wspec, shared_spec),
         out_specs=(xspec, P()),

@@ -19,6 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import use_pallas
 from repro.models.layers import dense_init, dt
 
 RG_CONV_WIDTH = 4
@@ -98,7 +99,7 @@ def rglru_full(cfg, p, x, h0=None, conv0=None, make_cache=False):
     beta = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12))
     b_in = beta * (gate_i * xc.astype(jnp.float32))
 
-    if cfg.use_pallas:
+    if use_pallas():
         from repro.kernels.rglru_scan.ops import rglru_scan_op
         h = rglru_scan_op(a, b_in,
                           h0.astype(jnp.float32) if h0 is not None
@@ -287,7 +288,7 @@ def rwkv_tmix_full(cfg, p, x, cache=None, make_cache=False):
     logw = -jnp.exp(ww).reshape(B, S, H, dk)                   # ≤ 0
     s0 = (cache["s"] if cache is not None
           else jnp.zeros((B, H, dk, dk), jnp.float32))
-    if cfg.use_pallas:
+    if use_pallas():
         from repro.kernels.rwkv6_wkv.ops import rwkv6_wkv_op
         ot, s_fin = rwkv6_wkv_op(
             r.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),

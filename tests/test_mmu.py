@@ -9,9 +9,10 @@ except ImportError:            # fall back to seeded-random sweeps
     from _hyp_fallback import given, settings, strategies as st
 
 from repro.core.isolation import IsolationAuditor
-from repro.core.mmu import (BACKENDS, BitmapAllocator, FreelistAllocator,
-                            IsolationViolation, OutOfMemory, QuotaExceeded,
-                            SegmentPool)
+from repro.core.mmu import (BACKENDS, HBM_PER_CHIP, BitmapAllocator,
+                            FreelistAllocator, IsolationViolation, MMUError,
+                            OutOfMemory, QuotaExceeded, SegmentPool,
+                            device_hbm_bytes)
 
 SEG = 1 << 20
 
@@ -19,6 +20,30 @@ SEG = 1 << 20
 def make_pool(backend, n_segs=64):
     return SegmentPool(total_bytes=n_segs * SEG, backend=backend,
                        segment_bytes=SEG, auditor=IsolationAuditor())
+
+
+class _Chip:
+    platform = "tpu"
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_device_hbm_bytes_from_memory_stats():
+    """A chip's pool capacity is what it reports, the smallest over the
+    slice; a chip that reports no limit is an error, never a guess. CPU
+    devices stand in for chips of HBM_PER_CHIP."""
+    import jax
+    chips = [_Chip({"bytes_limit": 16 << 30}),
+             _Chip({"bytes_limit": 15 << 30})]
+    assert device_hbm_bytes(chips) == 15 << 30
+    assert device_hbm_bytes(jax.devices("cpu")) == HBM_PER_CHIP
+    for stats in (None, {}, {"bytes_limit": 0}):
+        with pytest.raises(MMUError):
+            device_hbm_bytes([_Chip(stats)])
 
 
 @pytest.mark.parametrize("backend", sorted(BACKENDS))

@@ -108,3 +108,30 @@ def test_sanitize_drops_non_dividing_axes():
         ("data", "model")
     assert _sanitize((("data", "model"), None), (100, 4), sizes) == \
         (None, None)
+
+
+def test_train_step_on_local_mesh():
+    """Jitted train steps on a ``make_local_mesh`` mesh. The second step
+    takes the first one's sharded outputs, and its embedding gather over
+    the model-sharded table only resolves on Auto mesh axes: Explicit
+    axes (``jax.make_mesh``'s default) raise ShardingTypeError there."""
+    import jax.numpy as jnp
+
+    from repro import optim
+    from repro.configs.base import ShapeCell
+    from repro.data import pipeline_for
+    from repro.launch.mesh import make_local_mesh
+    from repro.parallel import build_train
+
+    cfg = get_config("qwen1.5-0.5b", reduced=True)
+    cell = ShapeCell("t", 16, 2, "train")
+    mesh = make_local_mesh((1, 1))
+    opt_cfg = optim.OptConfig(state_dtype=cfg.opt_dtype)
+    step, _ = build_train(cfg, mesh, cell, opt_cfg)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    batch = {k: jnp.asarray(v)
+             for k, v in pipeline_for(cfg, cell, seed=0).batch(0).items()}
+    state = (params, optim.init(opt_cfg, params))
+    for _ in range(2):
+        *state, metrics = step(*state, batch)
+        assert np.isfinite(float(metrics["loss"]))

@@ -82,20 +82,24 @@ def test_cross_slice_reprogram_attack_rejected():
 
 
 def test_compile_cache_warm_rebind():
-    """Same program + same topology class → warm hit, re-bound to the new
-    slice (compile_seconds == 0)."""
+    """Same program re-flashed on the same slice → warm hit
+    (compile_seconds == 0). A slice of the same topology class on other
+    devices compiles its own executable, bound to its own devices: the
+    first slice's executable would run on the first slice's chips."""
     svc = CompileService(step_builder=_fake_builder)
     req = ProgramRequest("qwen1.5-0.5b", "decode", 32, 2)
     vs0 = mkslice(0, base=0)
     vs1 = mkslice(1, base=50)
     bf0 = svc.compile(req, vs0)
     assert svc.misses == 1 and bf0.compile_seconds > 0
+    warm = svc.compile(req, vs0)
+    assert svc.hits == 1 and warm.compile_seconds == 0.0
+    assert warm.compiled is bf0.compiled
     bf1 = svc.compile(req, vs1)
-    assert svc.hits == 1
-    assert bf1.compile_seconds == 0.0
-    assert bf1.slice_fingerprint == vs1.fingerprint   # re-bound
+    assert svc.misses == 2 and bf1.compiled is not bf0.compiled
+    assert bf1.slice_fingerprint == vs1.fingerprint
     loader = ProgramLoader()
-    loader.load(bf1, vs1, quiesce_noop())             # legal after re-bind
+    loader.load(bf1, vs1, quiesce_noop())
 
 
 def _fake_builder(cfg, mesh, cell):
