@@ -65,9 +65,8 @@ def run():
     ts = vmm.transfer.stats
     guest_copy = ts.guest_copy_ns / iters
     dma = ts.dma_ns / iters
-    translate_s = reg.histogram("mmu_translate_s").summary()
-    mmu = (translate_s["mean"] * 1e9 if translate_s["count"] else 0.0) \
-        + t.pool.stats.alloc_latency_us() * 1e3
+    mmu = 1e9 * sum(reg.histogram(name).summary()["mean"]
+                    for name in ("mmu_translate_s", "mmu_alloc_s"))
     ops = vmm.stats()["ops"]
     # the warmup run is in the log too — average only the measured iters
     measured = [r.duration_ms for r in vmm.oplog.query(op="run")[n_runs0:]]

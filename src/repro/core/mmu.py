@@ -55,6 +55,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.obs import span
+
 SEGMENT_BYTES = 16 * 2 ** 20          # 16 MiB
 HBM_PER_CHIP = 16 * 2 ** 30           # a CPU-simulated chip (v5e size)
 
@@ -285,7 +287,6 @@ class MMUStats:
     allocs: int = 0
     frees: int = 0
     denied: int = 0
-    alloc_ns_total: int = 0
     peak_segs: int = 0
     # paging counters (PageTable API)
     pages_allocated: int = 0
@@ -296,9 +297,6 @@ class MMUStats:
     cow_forks: int = 0              # shared frames forked on first write
     swap_outs: int = 0              # table entries evicted to host tier
     swap_ins: int = 0               # refaults back onto fresh frames
-
-    def alloc_latency_us(self):
-        return (self.alloc_ns_total / max(self.allocs, 1)) / 1e3
 
 
 @dataclass
@@ -384,7 +382,8 @@ class SegmentPool:
 
     def alloc(self, n_bytes: int, owner: str) -> Allocation:
         n_segs = max(1, -(-n_bytes // self.segment_bytes))
-        t0 = time.perf_counter_ns()
+        t0 = time.perf_counter_ns() \
+            if self.obs is not None and self.obs.enabled else 0
         with self._lock:
             q = self.quota_segs.get(owner)
             if q is not None and self._owner_segs(owner) + n_segs > q:
@@ -406,13 +405,12 @@ class SegmentPool:
             a = Allocation(h, owner, start, n_segs, n_bytes)
             self.allocations[h] = a
             self.stats.allocs += 1
-            dt_ns = time.perf_counter_ns() - t0
-            self.stats.alloc_ns_total += dt_ns
             used = self.n_segments - self.alloc_backend.free_segments()
             self.stats.peak_segs = max(self.stats.peak_segs, used)
-            if self.obs is not None and self.obs.enabled:
+            if t0:
                 self.obs.count("mmu_allocs_total", owner=owner)
-                self.obs.observe("mmu_alloc_s", dt_ns / 1e9)
+                self.obs.observe("mmu_alloc_s",
+                                 (time.perf_counter_ns() - t0) / 1e9)
             return a
 
     def free(self, handle: int, owner: str):
@@ -530,7 +528,7 @@ class SegmentPool:
         the prefix-sharing admission path: logical blocks 0..k-1 are the
         shared prompt prefix, blocks k.. are private."""
         shared = list(shared_prefix or [])
-        with self._lock:
+        with span("mmu.alloc_pages", n=n), self._lock:
             for p in shared:
                 if p not in self.frame_refs:
                     raise MMUError(f"shared prefix frame {p} is not live")
@@ -550,7 +548,7 @@ class SegmentPool:
 
     def grow_pages(self, handle: int, owner: str, n: int = 1) -> PageTable:
         """Demand-grow a live table by ``n`` pages (a page fault)."""
-        with self._lock:
+        with span("mmu.grow_pages", n=n), self._lock:
             t = self._check_table(handle, owner, "cross_owner_grow")
             t.pages.extend(self._alloc_single_pages(n, owner))
             self.stats.page_faults += 1
@@ -562,7 +560,7 @@ class SegmentPool:
         """Return the table's mappings; each frame is freed only when
         its last reference (other tables, pins) drops. Swapped entries
         hold no frame and are simply dropped."""
-        with self._lock:
+        with span("mmu.free_pages"), self._lock:
             t = self._check_table(handle, owner, "cross_owner_free")
             for p in t.pages:
                 if p == SWAPPED:
@@ -735,7 +733,7 @@ class SegmentPool:
 
     def memory_stats(self) -> dict:
         """Paging/occupancy snapshot for VMM.stats()['memory']."""
-        with self._lock:
+        with span("mmu.memory_stats"), self._lock:
             return {
                 "segments_total": self.n_segments,
                 "segments_in_use":

@@ -47,7 +47,7 @@ from repro.core.scheduler import (IRQ_DEGRADED, IRQ_DONE,  # noqa: F401
 from repro.core.shell import CompletionQueue, TransferEngine
 from repro.core.tenant import GuestBuffer, GuestDevice, Tenant
 from repro.core.vslice import Floorplanner
-from repro.obs import NULL_HUB, ObsHub
+from repro.obs import NULL_HUB, ObsHub, span
 
 
 class AdmissionError(Exception):
@@ -289,7 +289,8 @@ class VMM:
 
     def _run_work(self, t: Tenant, args, kw):
         def work():
-            out = t.program(*args, **kw)
+            with span("vmm.program"):
+                out = t.program(*args, **kw)
             t.cq.raise_event(IRQ_DONE, "run_done", {"step": t.step})
             t.step += 1
             return out
@@ -318,8 +319,9 @@ class VMM:
     def op_run(self, t: Tenant, *args, **kw):
         if t.program is None:
             raise LegalityError("no program loaded — reprogram first")
-        return self.plane.execute(t, "run", self._run_work(t, args, kw),
-                                  {"step": t.step})
+        with span("vmm.run", step=t.step):
+            return self.plane.execute(t, "run", self._run_work(t, args, kw),
+                                      {"step": t.step})
 
     def op_run_async(self, t: Tenant, *args, **kw):
         """Async data-plane submission: returns a Future for the run."""

@@ -1,5 +1,6 @@
 """vPOD telemetry plane — metrics registry, request tracing, flight
-recorder, behind one :class:`ObsHub`.
+recorder behind one :class:`ObsHub`, and program spans on the
+profiler's clock.
 
 Usage from instrumented code (VMM, data planes, MMU pools, serving
 engines)::
@@ -10,12 +11,21 @@ engines)::
         hub.tracer.start("a", rid)
         hub.flight.record("a", "queue_buildup", {"depth": 80})
 
+    with span("kv.ensure", rid=rid, start=start):
+        ...
+
 The hub is a **no-op when disabled**: ``enabled`` is False, and every
 convenience method returns immediately — instrumentation sites guard
 their work with ``if hub.enabled`` so the disabled-mode cost on a hot
-path is one attribute check (measured, not assumed:
-``benchmarks/obs_overhead.py`` pins disabled overhead < 1% and
-enabled < 5% on the paged-KV serving path).
+path is one attribute check.
+
+The fourth part, :func:`span` (``obs/spans.py``), takes no hub: it
+writes a named host event into the trace ``jax.profiler`` is collecting,
+on the device's clock, and is one ``TraceAnnotation.is_enabled()`` check
+when no trace runs. Measured on a TPU v5e host: a site costs 0.5 us with
+no trace and 2 us under one, about 15 sites per engine step; served
+through the VMM, the mean gap between tokens read the same with the
+sites in as without them, within the run-to-run spread (PERF.md).
 
 A module-level :data:`NULL_HUB` (disabled) is the default everywhere a
 component takes an ``obs=`` parameter, so un-instrumented construction
@@ -31,6 +41,7 @@ from repro.obs.trace import (MAX_EVENTS, PHASE_ADMITTED, PHASE_DECODE,
                              PHASE_QUEUED, PHASE_REFAULT, PHASE_SWAP_OUT,
                              RequestTracer,
                              Span)
+from repro.obs.spans import span
 
 
 class ObsHub:
@@ -93,5 +104,5 @@ __all__ = [
     "NULL_HUB", "ObsHub", "PHASE_ADMITTED", "PHASE_DECODE",
     "PHASE_DEFERRED", "PHASE_DENIED", "PHASE_DONE", "PHASE_PREFILL",
     "PHASE_PREFILL_CHUNK", "PHASE_QUEUED", "PHASE_REFAULT",
-    "PHASE_SWAP_OUT", "RequestTracer", "Span", "TRIGGER_KINDS",
+    "PHASE_SWAP_OUT", "RequestTracer", "Span", "TRIGGER_KINDS", "span",
 ]

@@ -38,7 +38,7 @@ from repro.analysis.lock_watchdog import note_callback
 from repro.core.mmu import MMUError
 from repro.obs import (NULL_HUB, PHASE_ADMITTED, PHASE_DECODE,
                        PHASE_DEFERRED, PHASE_PREFILL, PHASE_PREFILL_CHUNK,
-                       PHASE_REFAULT, PHASE_SWAP_OUT)
+                       PHASE_REFAULT, PHASE_SWAP_OUT, span)
 from repro.serving.paged_kv import PagedKVCache
 
 
@@ -256,6 +256,10 @@ class ServeEngine:
         return batch
 
     def _admit(self, params):
+        with span("engine.admit"):
+            self._admit_body(params)
+
+    def _admit_body(self, params):
         for i in range(self.B):
             if self.slots[i] is not None:
                 continue
@@ -348,8 +352,9 @@ class ServeEngine:
                     break
                 self.stats.state_pages_leased += self.rstate.blocks_per_slot
             if self._row_reset_fn is not None:
-                self.kv.state = self._row_reset_fn(self.kv.state,
-                                                   np.int32(i))
+                with span("engine.row_reset", slot=i):
+                    self.kv.state = self._row_reset_fn(self.kv.state,
+                                                       np.int32(i))
             if shared:
                 self.stats.shared_prefix_hits += 1
                 self.stats.shared_prefix_tokens += shared
@@ -377,7 +382,8 @@ class ServeEngine:
             if self.obs.enabled:
                 self.obs.tracer.event(self.obs_tenant, req.rid,
                                       PHASE_PREFILL, tokens=plen)
-            logits = np.asarray(jax.device_get(logits), np.float32)
+            with span("engine.fetch"):
+                logits = np.asarray(jax.device_get(logits), np.float32)
             if self._logits is None:
                 self._logits = np.zeros((self.B, logits.shape[-1]),
                                         np.float32)
@@ -432,6 +438,10 @@ class ServeEngine:
         the budget fairly). Chunks are never split below
         min(chunk_tokens, remaining) — the compile universe stays one
         shape per (chunk_tokens, prompt_len % chunk_tokens) pair."""
+        with span("engine.prefill_chunks"):
+            self._prefill_chunks_body(params)
+
+    def _prefill_chunks_body(self, params):
         prefilling = [i for i in range(self.B)
                       if self.slots[i] is not None and self._cursor[i] >= 0]
         if not prefilling:
@@ -454,7 +464,8 @@ class ServeEngine:
                 # write_from=start makes the whole chunk window privately
                 # writable — a warm request writing past its shared span
                 # into a partially-filled shared page CoW-forks it here.
-                self.kv.ensure(i, start + c - 1, write_from=start)
+                with span("kv.ensure", rid=req.rid, start=start):
+                    self.kv.ensure(i, start + c - 1, write_from=start)
                 grown = self.kv.tables[i].n_pages - before
                 self.stats.page_faults += grown
                 self.stats.pages_leased += grown
@@ -464,10 +475,13 @@ class ServeEngine:
                 self.stats.pages_leased += grown
                 self._abort_prefill(i, exc)
                 continue
-            tokens = jnp.asarray(req.prompt[None, start:start + c])
-            logits, self.kv.state = self._chunk_fn(
-                params, self.kv.state, tokens, jnp.int32(i),
-                jnp.asarray(self.kv.block_tables()[i]), jnp.int32(start))
+            with span("engine.inputs"):
+                with span("engine.block_tables"):
+                    bt = jnp.asarray(self.kv.block_tables()[i])
+                args = (jnp.asarray(req.prompt[None, start:start + c]),
+                        jnp.int32(i), bt, jnp.int32(start))
+            logits, self.kv.state = self._chunk_fn(params, self.kv.state,
+                                                   *args)
             self._cursor[i] = start + c
             self.stats.prefill_chunks += 1
             if self.obs.enabled:
@@ -480,7 +494,8 @@ class ServeEngine:
                 # prefill complete: sample the first token from the last
                 # chunk's logits (the one host round-trip per request),
                 # then the slot joins the fused decode batch
-                lg = np.asarray(jax.device_get(logits), np.float32)[0]
+                with span("engine.fetch"):
+                    lg = np.asarray(jax.device_get(logits), np.float32)[0]
                 self._next[i] = self._sample_one(lg, req.temperature)
                 self._cursor[i] = -1
                 self.positions[i] = plen
@@ -632,9 +647,11 @@ class ServeEngine:
         slot, recycle EOS/budget-exhausted slots, advance decode with
         per-slot positions. Returns the requests that finished."""
         if not self.obs.enabled:
-            return self._step(params)
+            with span("engine.step"):
+                return self._step(params)
         t0 = time.perf_counter()
-        finished = self._step(params)
+        with span("engine.step"):
+            finished = self._step(params)
         self.obs.observe("engine_step_s", time.perf_counter() - t0,
                          tenant=self.obs_tenant)
         return finished
@@ -662,69 +679,73 @@ class ServeEngine:
         if not active:
             return finished
         self.stats.steps += 1
-        nxt = (self._next if self._chunked
-               else self._sample(self._logits, active))
-        token = np.zeros((self.B, 1), np.int32)
-        for i in active:
-            r = self.slots[i]
-            if i in self._emitted_parked:
-                # first step after a mid-step park resumed: _next[i] was
-                # already emitted in the step that parked this slot —
-                # feed it to decode for its pending KV write, once,
-                # without emitting it a second time
-                self._emitted_parked.discard(i)
-                token[i, 0] = int(nxt[i])
-                continue
-            if len(r.out_tokens) >= r.max_new_tokens:   # zero-budget case
-                self._finish(i, finished)
-                continue
-            tok = int(nxt[i])
-            r.out_tokens.append(tok)
-            self.stats.generated_tokens += 1
-            if self.obs.enabled:
-                self.obs.tracer.token(self.obs_tenant, r.rid)
-            token[i, 0] = tok
-            if tok == self.eos_id or len(r.out_tokens) >= r.max_new_tokens:
-                self._finish(i, finished)
-            elif self.positions[i] >= self.capacity:
-                self._finish(i, finished)               # KV budget: truncate
-        for i in [i for i in range(self.B) if self.slots[i] is not None
-                  and self.positions[i] >= 0]:
-            if self.positions[i] < 0:
-                continue      # parked by an earlier slot's swap relief
-            # demand paging — counters track engine-local deltas, never
-            # the pool-global ones (a shared --virtualized tenant pool
-            # serves other engines too); demand-grown pages count as
-            # leased so pages_leased/pages_freed balance at EOS
-            before = self.kv.tables[i].n_pages
-            try:
+        with span("engine.emit"):
+            nxt = (self._next if self._chunked
+                   else self._sample(self._logits, active))
+            token = np.zeros((self.B, 1), np.int32)
+            for i in active:
+                r = self.slots[i]
+                if i in self._emitted_parked:
+                    # first step after a mid-step park resumed: _next[i] was
+                    # already emitted in the step that parked this slot —
+                    # feed it to decode for its pending KV write, once,
+                    # without emitting it a second time
+                    self._emitted_parked.discard(i)
+                    token[i, 0] = int(nxt[i])
+                    continue
+                if len(r.out_tokens) >= r.max_new_tokens:   # zero-budget case
+                    self._finish(i, finished)
+                    continue
+                tok = int(nxt[i])
+                r.out_tokens.append(tok)
+                self.stats.generated_tokens += 1
+                if self.obs.enabled:
+                    self.obs.tracer.token(self.obs_tenant, r.rid)
+                token[i, 0] = tok
+                if tok == self.eos_id or len(r.out_tokens) >= r.max_new_tokens:
+                    self._finish(i, finished)
+                elif self.positions[i] >= self.capacity:
+                    self._finish(i, finished)           # KV budget: truncate
+        # one span over every decoding slot's demand paging, not one per
+        # slot
+        with span("kv.ensure"):
+            for i in [i for i in range(self.B) if self.slots[i] is not None
+                      and self.positions[i] >= 0]:
+                if self.positions[i] < 0:
+                    continue      # parked by an earlier slot's swap relief
+                # demand paging — counters track engine-local deltas, never
+                # the pool-global ones (a shared --virtualized tenant pool
+                # serves other engines too); demand-grown pages count as
+                # leased so pages_leased/pages_freed balance at EOS
+                before = self.kv.tables[i].n_pages
                 try:
-                    self.kv.ensure(i, int(self.positions[i]))
-                except MMUError:
-                    if not self._swap:
-                        raise
-                    # swap relief: park another decoder so this slot's
-                    # page fault can be served; with no other decoder to
-                    # shed, suspend this slot itself — it resumes (and
-                    # completes its pending KV write) once pages free up
-                    if self._swap_out_victim(exclude=i, mid_step=True):
+                    try:
                         self.kv.ensure(i, int(self.positions[i]))
-                    elif self._park(i, mid_step=True):
-                        continue
-                    else:
-                        raise
-                grown = self.kv.tables[i].n_pages - before
-                self.stats.page_faults += grown
-                self.stats.pages_leased += grown
-            except MMUError:
-                # a shared pool ran dry mid-decode: truncate this slot
-                # (its sampled tokens are already delivered) rather than
-                # wedge the whole batch — pages grown before the failure
-                # are still accounted before _finish frees the table
-                grown = self.kv.tables[i].n_pages - before
-                self.stats.page_faults += grown
-                self.stats.pages_leased += grown
-                self._finish(i, finished)
+                    except MMUError:
+                        if not self._swap:
+                            raise
+                        # swap relief: park another decoder so this slot's
+                        # page fault can be served; with no other decoder to
+                        # shed, suspend this slot itself — it resumes (and
+                        # completes its pending KV write) once pages free up
+                        if self._swap_out_victim(exclude=i, mid_step=True):
+                            self.kv.ensure(i, int(self.positions[i]))
+                        elif self._park(i, mid_step=True):
+                            continue
+                        else:
+                            raise
+                    grown = self.kv.tables[i].n_pages - before
+                    self.stats.page_faults += grown
+                    self.stats.pages_leased += grown
+                except MMUError:
+                    # a shared pool ran dry mid-decode: truncate this slot
+                    # (its sampled tokens are already delivered) rather than
+                    # wedge the whole batch — pages grown before the failure
+                    # are still accounted before _finish frees the table
+                    grown = self.kv.tables[i].n_pages - before
+                    self.stats.page_faults += grown
+                    self.stats.pages_leased += grown
+                    self._finish(i, finished)
         remaining = [i for i in range(self.B) if self.slots[i] is not None
                      and self.positions[i] >= 0]
         if not remaining:
@@ -736,20 +757,27 @@ class ServeEngine:
             temps = np.zeros(self.B, np.float32)
             for i in remaining:
                 temps[i] = self.slots[i].temperature
-            toks, self.kv.state = self._fused_fn(
-                params, self.kv.state, jnp.asarray(token),
-                jnp.asarray(self.positions),
-                jnp.asarray(self.kv.block_tables()), jnp.asarray(temps),
-                jnp.int32(self.stats.steps))
-            toks = np.asarray(jax.device_get(toks))
+            with span("engine.inputs"):
+                with span("engine.block_tables"):
+                    bt = jnp.asarray(self.kv.block_tables())
+                args = (jnp.asarray(token), jnp.asarray(self.positions), bt,
+                        jnp.asarray(temps), jnp.int32(self.stats.steps))
+            toks, self.kv.state = self._fused_fn(params, self.kv.state,
+                                                 *args)
+            with span("engine.fetch"):
+                toks = np.asarray(jax.device_get(toks))
             for i in remaining:
                 self._next[i] = int(toks[i])
         else:
-            logits, self.kv.state = self._decode_fn(
-                params, self.kv.state, jnp.asarray(token),
-                jnp.asarray(self.positions),
-                jnp.asarray(self.kv.block_tables()))
-            self._logits = np.asarray(jax.device_get(logits), np.float32)
+            with span("engine.inputs"):
+                with span("engine.block_tables"):
+                    bt = jnp.asarray(self.kv.block_tables())
+                args = (jnp.asarray(token), jnp.asarray(self.positions), bt)
+            logits, self.kv.state = self._decode_fn(params, self.kv.state,
+                                                    *args)
+            with span("engine.fetch"):
+                self._logits = np.asarray(jax.device_get(logits),
+                                          np.float32)
         if self.obs.enabled:
             for i in remaining:
                 self.obs.tracer.event(self.obs_tenant, self.slots[i].rid,

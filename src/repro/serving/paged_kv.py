@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.core.mmu import SWAPPED, OutOfMemory, SegmentPool
 from repro.kernels.common import cdiv
+from repro.obs import span
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.swap import HostSwapTier
 
@@ -147,38 +148,40 @@ class PagedKVCache:
         are mapped by reference and the return value is the number of
         prompt tokens the cache already covers (the engine starts its
         prefill cursor past them). Returns 0 on a cold admission."""
-        assert self.tables[slot] is None, f"slot {slot} still leased"
-        shared, shared_frames = 0, []
-        if self.prefix is not None and prompt is not None:
-            # the last prompt token is always prefilled — its logits
-            # seed sampling — so the shareable span is plen - 1
-            shared, shared_frames = self.prefix.lookup(
-                prompt, max_tokens=prompt_len - 1)
-        cover = prompt_len
-        if lease_len is not None:
-            cover = min(prompt_len, shared + lease_len)
-        n_blocks = max(1, cdiv(cover, self.page_size))
-        n_new = max(0, n_blocks - len(shared_frames))
-        # one slot's worth of pages is each request-owner's quota
-        self.pool.set_quota(owner, self.blocks_per_slot
-                            * self.pool.segment_bytes)
-        try:
-            table = self._with_evict(
-                lambda: self.pool.alloc_pages(
-                    n_new, owner, shared_prefix=shared_frames or None))
-        except Exception:
-            self.pool.clear_quota(owner)     # failed lease: no stale entry
-            raise
-        self.tables[slot] = table
-        self.owners[slot] = owner
-        self._bt[slot, :] = 0
-        self._bt[slot, :table.n_pages] = table.pages
-        if shared:
-            self.prefix_hits += 1
-            self.shared_tokens_total += shared
-            if self.obs is not None and self.obs.enabled:
-                self.obs.count("kv_shared_pages_total", len(shared_frames))
-        return shared
+        with span("kv.admit", slot=slot, owner=owner):
+            assert self.tables[slot] is None, f"slot {slot} still leased"
+            shared, shared_frames = 0, []
+            if self.prefix is not None and prompt is not None:
+                # the last prompt token is always prefilled — its logits
+                # seed sampling — so the shareable span is plen - 1
+                shared, shared_frames = self.prefix.lookup(
+                    prompt, max_tokens=prompt_len - 1)
+            cover = prompt_len
+            if lease_len is not None:
+                cover = min(prompt_len, shared + lease_len)
+            n_blocks = max(1, cdiv(cover, self.page_size))
+            n_new = max(0, n_blocks - len(shared_frames))
+            # one slot's worth of pages is each request-owner's quota
+            self.pool.set_quota(owner, self.blocks_per_slot
+                                * self.pool.segment_bytes)
+            try:
+                table = self._with_evict(
+                    lambda: self.pool.alloc_pages(
+                        n_new, owner, shared_prefix=shared_frames or None))
+            except Exception:
+                self.pool.clear_quota(owner)  # failed lease: no stale entry
+                raise
+            self.tables[slot] = table
+            self.owners[slot] = owner
+            self._bt[slot, :] = 0
+            self._bt[slot, :table.n_pages] = table.pages
+            if shared:
+                self.prefix_hits += 1
+                self.shared_tokens_total += shared
+                if self.obs is not None and self.obs.enabled:
+                    self.obs.count("kv_shared_pages_total",
+                                   len(shared_frames))
+            return shared
 
     def _with_evict(self, fn):
         """Run an allocating MMU op; on OutOfMemory shed prefix-cache
@@ -221,16 +224,17 @@ class PagedKVCache:
     def release(self, slot: int):
         """EOS recycling: return the slot's pages to the pool (shared
         frames just drop a ref) and discard any swapped payloads."""
-        table = self.tables[slot]
-        if table is None:
-            return
-        if self.swap_tier is not None:
-            self.swap_tier.drop(table.handle)
-        self.pool.free_pages(table.handle, self.owners[slot])
-        self.pool.clear_quota(self.owners[slot])
-        self.tables[slot] = None
-        self.owners[slot] = None
-        self._bt[slot, :] = 0
+        with span("kv.release", slot=slot):
+            table = self.tables[slot]
+            if table is None:
+                return
+            if self.swap_tier is not None:
+                self.swap_tier.drop(table.handle)
+            self.pool.free_pages(table.handle, self.owners[slot])
+            self.pool.clear_quota(self.owners[slot])
+            self.tables[slot] = None
+            self.owners[slot] = None
+            self._bt[slot, :] = 0
 
     # ------------------------------------------------------------------
     # Page hierarchy: sharing / copy-on-write / swap

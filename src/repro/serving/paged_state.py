@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core.mmu import SWAPPED, SegmentPool
 from repro.kernels.common import cdiv
+from repro.obs import span
 from repro.serving.swap import HostSwapTier
 
 
@@ -83,32 +84,34 @@ class PagedRecurrentState:
         request exactly as it does for a bounced KV lease."""
         if not self.enabled:
             return
-        assert self.tables[slot] is None, f"slot {slot} still leased"
-        so = self._owner(owner)
-        self.pool.set_quota(so, self.blocks_per_slot * self.page_bytes)
-        try:
-            table = self.pool.alloc_pages(self.blocks_per_slot, so)
-        except Exception:
-            self.pool.clear_quota(so)        # failed lease: no stale entry
-            raise
-        self.tables[slot] = table
-        self.owners[slot] = so
-        self.pages_leased += self.blocks_per_slot
-        if self.obs is not None and self.obs.enabled:
-            self.obs.count("state_pages_leased_total",
-                           self.blocks_per_slot)
+        with span("kv.admit", slot=slot, owner=owner):
+            assert self.tables[slot] is None, f"slot {slot} still leased"
+            so = self._owner(owner)
+            self.pool.set_quota(so, self.blocks_per_slot * self.page_bytes)
+            try:
+                table = self.pool.alloc_pages(self.blocks_per_slot, so)
+            except Exception:
+                self.pool.clear_quota(so)  # failed lease: no stale entry
+                raise
+            self.tables[slot] = table
+            self.owners[slot] = so
+            self.pages_leased += self.blocks_per_slot
+            if self.obs is not None and self.obs.enabled:
+                self.obs.count("state_pages_leased_total",
+                               self.blocks_per_slot)
 
     def release(self, slot: int):
         """EOS recycling: drop any parked payload, free the pages."""
-        table = self.tables[slot]
-        if table is None:
-            return
-        self.tier.drop(table.handle)
-        self.pages_freed += table.n_pages
-        self.pool.free_pages(table.handle, self.owners[slot])
-        self.pool.clear_quota(self.owners[slot])
-        self.tables[slot] = None
-        self.owners[slot] = None
+        with span("kv.release", slot=slot):
+            table = self.tables[slot]
+            if table is None:
+                return
+            self.tier.drop(table.handle)
+            self.pages_freed += table.n_pages
+            self.pool.free_pages(table.handle, self.owners[slot])
+            self.pool.clear_quota(self.owners[slot])
+            self.tables[slot] = None
+            self.owners[slot] = None
 
     def reset(self, state, slot: int):
         """Zero the slot's rows — a freshly admitted request must not

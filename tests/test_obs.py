@@ -384,3 +384,111 @@ def test_engine_deferred_span_on_pool_pressure(rng_key):
     assert span.status == "done"             # eventually admitted + served
     snap = obs.tracer.snapshot()
     assert snap["denials"].get("serve:pool_pressure", 0) >= 1
+
+
+# ===========================================================================
+# Program spans on the profiler's clock
+# ===========================================================================
+
+#: every span site in the program, by name
+SPAN_NAMES = ("engine.step", "engine.admit", "engine.prefill_chunks",
+              "engine.inputs", "engine.block_tables", "engine.fetch",
+              "engine.row_reset", "engine.emit", "kv.admit", "kv.release", "kv.ensure", "mmu.alloc_pages",
+              "mmu.grow_pages", "mmu.free_pages", "mmu.memory_stats",
+              "vmm.run", "vmm.program")
+
+
+def test_span_is_a_shared_noop_without_a_trace(monkeypatch):
+    from jax.profiler import TraceAnnotation
+    from repro.obs import span, spans
+
+    assert not TraceAnnotation.is_enabled()
+    assert span("engine.step") is span("kv.ensure", rid=1, start=0)
+
+    class Fake:
+        on = False
+        built = []
+
+        @staticmethod
+        def is_enabled():
+            return Fake.on
+
+        def __init__(self, name, **args):
+            Fake.built.append((name, args))
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Fake)
+    off = span("kv.ensure", rid=1, start=0)
+    with off:
+        pass
+    assert off is span("engine.step") and Fake.built == []
+    Fake.on = True
+    assert isinstance(span("kv.ensure", rid=1, start=0), Fake)
+    assert Fake.built == [("kv.ensure", {"rid": 1, "start": 0})]
+
+
+def test_program_spans_land_in_the_profiler_trace(tmp_path, rng_key):
+    """A chunked engine over a VMM tenant with paged recurrent state,
+    served under ``jax.profiler``: every span site shows up in the trace
+    the benchmark reads, the MMU's and the KV layer's inside an engine
+    step, the callable inside its mediated run."""
+    import sys
+    from pathlib import Path
+
+    import jax
+    from jax.sharding import Mesh
+    from repro.configs import get_config
+    from repro.core import VMM
+    from repro.models import build_model
+    from repro.serving import ServeEngine, pool_pressure_gate
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import trace
+
+    cfg = get_config("rwkv6-7b", reduced=True)
+    model = build_model(cfg)
+    params = model.init(rng_key)
+    devs = np.array(jax.devices()[:1]).reshape(1, 1)
+    vmm = VMM(Mesh(devs, ("data", "model")), policy="hybrid",
+              ckpt_root=tempfile.mkdtemp())
+    try:
+        tenant = vmm.create_vm("server", (1, 1))
+        tenant.device.open()
+        wrap = _mediate(tenant)
+        eng = ServeEngine(cfg, model, 2, 64, page_size=8, pool=tenant.pool,
+                          prefill_wrap=wrap, decode_wrap=wrap,
+                          admission_gate=pool_pressure_gate(tenant.pool),
+                          chunk_tokens=8, state_paging=True)
+        prompts = [np.arange(20) % cfg.vocab, np.arange(11) % cfg.vocab]
+        for p in prompts:                       # compile outside the trace
+            eng.submit(p, max_new_tokens=3)
+        eng.run_round(params)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
+                # 20 prompt tokens over 8-token pages: the second chunk
+                # and the decode past position 24 grow the page table
+                eng.submit(prompts[0], max_new_tokens=12)
+                eng.submit(prompts[1], max_new_tokens=3)
+                eng.run_round(params)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        vmm.shutdown()
+
+    tr = trace.load(trace.latest_xplane(str(tmp_path)))
+    by_name = {}
+    for name, s, e in tr.host_spans:
+        by_name.setdefault(name, []).append((s, e))
+    assert set(SPAN_NAMES) <= set(by_name), \
+        set(SPAN_NAMES) - set(by_name)
+
+    def inside(inner, outer):
+        return all(any(a <= s and e <= b for a, b in by_name[outer])
+                   for s, e in by_name[inner])
+    for name in SPAN_NAMES:
+        if name.startswith(("kv.", "mmu.")):
+            assert inside(name, "engine.step"), name
+    assert inside("vmm.program", "vmm.run")
+    assert len(by_name["vmm.run"]) == len(by_name["vmm.program"])
