@@ -13,25 +13,31 @@ Two cache layouts share the online-softmax body:
   (B, Hkv, C, hd) with one shared scalar ``pos`` (the reference).
 * ``paged_decode_attention`` — a shared physical page pool
   (num_pages, page_size, Hkv, hd) plus per-slot block tables and lengths.
-  Both the block table and the lengths vector are scalar-prefetched into
-  SMEM so each grid step's page index is known before the body runs — the
-  page DMA address is computed from the table, which is what makes the
-  virtual→physical walk free. Pages are linear (token t of slot b lives
-  at page ``bt[b, t // ps]``, offset ``t % ps``; no ring), so validity is
-  a simple ``t < lengths[b]`` mask and out-of-table grid steps (padded
-  block-table entries) mask to -inf and contribute nothing.
-  ``page_size`` should be a multiple of the 128-lane tile on real TPU;
-  small pages are fine in interpret mode.
+  Both are scalar-prefetched into SMEM; the pools stay in HBM. One grid
+  step walks every slot, and for each only the pages that hold its valid
+  tokens: pages below ``ceil(lengths[b] / page_size)`` and, with a window,
+  from the page holding ``lengths[b] - window`` on. It copies them a
+  compute block of several pages at a time, one DMA per page addressed
+  from the block table (the virtual→physical walk), into two VMEM buffers
+  that alternate, so the next block's pages are in flight while this one
+  is computed. The pages per block come from a page's bytes against a
+  fixed VMEM budget (``PAGED_VMEM_BYTES``), at most the table's width.
+  Pages are linear (token t of slot b lives at page ``bt[b, t // ps]``,
+  offset ``t % ps``; no ring). Padded block-table entries are never read,
+  a dead slot (length 0) copies nothing and returns zeros, and rows of a
+  block that hold no valid token are masked in V as well as in the
+  scores, so whatever they hold never reaches the output.
 
 Fused serving-step kernels (PR 7):
 
 * ``fused_paged_decode_attention`` — the paged sweep with the *new*
   token's K/V fused in-register: the freshly projected (B, Hkv, 1, hd)
-  K/V rides in VMEM and is substituted for pool row ``lengths-1`` during
-  the sweep, so decode attention no longer serializes behind the HBM
-  scatter that persists it (the scatter still runs, concurrently, to
-  keep the pool current for the *next* step — but this step never reads
-  the page it just wrote).
+  K/V rides in VMEM and starts each slot's online softmax in place of
+  pool row ``lengths-1``, which the sweep never reads (it covers the
+  pool's tokens below ``lengths-1``), so decode attention no longer
+  serializes behind the HBM scatter that persists it (the scatter still
+  runs, concurrently, to keep the pool current for the *next* step — but
+  this step never reads the row it just wrote).
 * ``sample_tokens`` — on-device argmax/Gumbel-max sampling over the
   final logits. ``argmax(logits + g·T)`` with Gumbel noise ``g`` equals
   softmax sampling at temperature ``T`` and degrades to greedy argmax at
@@ -136,63 +142,197 @@ def decode_attention(q, k, v, pos, *, window=0, interpret=False, bkv=BKV):
 # Paged variant: block-table walk over a shared physical page pool
 # ===========================================================================
 #
-# One grid step reads one whole page, all KV heads of it: a
-# (page_size, Hkv, hd) block whose last two dims are the pool's own, which
-# is the block shape the TPU compiler accepts (a single-head (ps, 1, hd)
-# block is refused). The query heads of each KV group are then handled
-# together, as G rows of one (G, ps) score tile.
+# The pools are not blocked (``memory_space=pl.ANY``): the kernel copies
+# what it reads itself, seeing a page of (ps, Hkv, hd) as ``ps * rt`` rows
+# of ``lanes`` (``rt`` rows per token, one per KV head, or ``hpr`` heads
+# side by side in 128 lanes where hd is narrower). The whole sweep is one
+# grid step. Its body walks the slots in order and, for each, only the
+# compute blocks that hold the slot's valid pages: ``ppb`` pages per
+# block, one DMA per page, its address read from the block table in SMEM.
+# Two VMEM buffers alternate, so the next block's pages (at a slot's last
+# block, the next slot's first) are in flight while this block is
+# computed. Pages at or past ``ceil(len / ps)``, and with a window those
+# before the page holding ``len - window``, are never copied; a dead slot
+# copies nothing and its output stays zero.
+#
+# A block is computed for all heads at once: the (Hq, lanes) queries
+# against all of the block's rows in one matmul, each query row keeping
+# only the columns of its own KV head; then one matmul with V. Rows that
+# were not copied hold whatever the buffer held before, so V is masked by
+# row as well as the scores by column.
+
+#: VMEM bytes for the double-buffered K and V blocks; the pages per compute
+#: block follow from a page's bytes
+PAGED_VMEM_BYTES = 4 * 1024 * 1024
 
 
-def _paged_kernel(len_ref, bt_ref, q_ref, *refs, scale, ps, nb, window,
-                  hkv, g, fused):
+def _pages_per_block(page_bytes, nb):
+    """Pages per compute block: as many as the double-buffered K and V
+    blocks fit in ``PAGED_VMEM_BYTES``, at most the table's width."""
+    return max(1, min(nb, PAGED_VMEM_BYTES // (4 * page_bytes)))
+
+
+def _heads_per_row(hkv, hd):
+    """KV heads side by side in one row of the pool's view: a pool whose
+    head is narrower than the 128 lanes cannot be sliced by page."""
+    hpr = 128 // hd if hd < 128 and 128 % hd == 0 else 1
+    return hpr if hkv % hpr == 0 else 1
+
+
+def _paged_kernel(len_ref, bt_ref, q_ref, *refs, scale, ps, nb, ppb, rt,
+                  gh, window, fused):
     if fused:
-        kn_ref, vn_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    j = pl.program_id(1)                              # logical block index
+        kn_ref, vn_ref, *refs = refs
+    (k_pool, v_pool, o_ref, kbuf, vbuf, sems, nxt_ref, acc_ref, m_ref,
+     l_ref) = refs
+    nslots = len_ref.shape[0]
+    rows_pp = ps * rt                                 # rows per page
+    # the pools as rows: reshaped here, since a reshape in the caller
+    # makes XLA copy the layer's pool before every call
+    k_pool, v_pool = (x.reshape(x.shape[0], rows_pp, x.shape[-1])
+                    for x in (k_pool, v_pool))
+    nrows = ppb * rows_pp                             # rows per block
+    hq = q_ref.shape[1]
+    # the KV head row (within a token) of each query row
+    head_row = jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (hq, nrows), 0), gh)
+    col_row = jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (hq, nrows), 1), rt)
+    own_head = head_row == col_row                    # (Hq, nrows)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def span(b):
+        """Slot b's pool tokens [lo, hi), first page and block count. The
+        fused step's newest token is not in the pool."""
+        length = len_ref[b]
+        lo = jnp.maximum(length - window, 0) if window > 0 else 0
+        hi = jnp.maximum(length - 1, 0) if fused else length
+        first = lo // ps
+        nblk = jnp.maximum((hi + ps - 1) // ps - first + ppb - 1, 0) // ppb
+        return lo, hi, first, nblk
 
-    length = len_ref[b]                   # fused: includes the new token
-    tok = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    valid = tok < length                              # linear, no ring
-    if window > 0:
-        valid &= tok >= length - window
-    if fused:
-        # the new token lives at logical index length-1 but is NOT in the
-        # pool yet: substitute its VMEM-resident row into the sweep
-        is_new = (j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-                  == length - 1)
-    for h in range(hkv):
-        rows = slice(h * g, (h + 1) * g)
-        q = q_ref[0, rows, :]                         # (G, hd)
-        k = k_ref[0, :, h, :]                         # (ps, hd)
-        v = v_ref[0, :, h, :]
-        if fused:
-            k = jnp.where(is_new, kn_ref[0, h:h + 1, :], k)
-            v = jnp.where(is_new, vn_ref[0, h:h + 1, :], v)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, _NEG)                 # (G, ps)
-        m_prev = m_ref[rows, :1]
+    def page_copy(buf, hbm, phys, slot, i, sem):
+        return pltpu.make_async_copy(
+            hbm.at[phys], buf.at[slot, pl.ds(i * rows_pp, rows_pp)], sem)
+
+    def pages(b, j):
+        _, hi, first, _ = span(b)
+        p0 = first + j * ppb
+        return p0, jnp.minimum(ppb, (hi + ps - 1) // ps - p0)
+
+    def start(b, j, slot):
+        p0, n = pages(b, j)
+
+        def one(i, c):
+            phys = bt_ref[b * nb + p0 + i]
+            page_copy(kbuf, k_pool, phys, slot, i, sems.at[0, slot]).start()
+            page_copy(vbuf, v_pool, phys, slot, i, sems.at[1, slot]).start()
+            return c
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    def wait(b, j, slot):
+        def one(i, c):
+            page_copy(kbuf, k_pool, 0, slot, 0, sems.at[0, slot]).wait()
+            page_copy(vbuf, v_pool, 0, slot, 0, sems.at[1, slot]).wait()
+            return c
+
+        jax.lax.fori_loop(0, pages(b, j)[1], one, 0)
+
+    def compute(b, j, slot):
+        lo, hi, first, _ = span(b)
+        base = (first + j * ppb) * ps                 # block's first token
+        # token t < hi of this block <=> its rows lie below (hi - base)·rt
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, nrows), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (nrows, 1), 0)
+        col_ok = col < (hi - base) * rt
+        row_ok = row < (hi - base) * rt
+        if window > 0:
+            col_ok &= col >= (lo - base) * rt
+            row_ok &= row >= (lo - base) * rt
+        valid = own_head & col_ok                     # (Hq, nrows)
+        k = kbuf[slot]                                # (nrows, lanes)
+        v = jnp.where(row_ok, vbuf[slot], 0)          # never 0 * garbage
+        s = jax.lax.dot_general(q_ref[b], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(valid, s * scale, _NEG)
+        m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_ref[rows, :] = l_ref[rows, :] * alpha + p.sum(-1, keepdims=True)
-        acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[rows, :] = jnp.broadcast_to(m_new, (g, m_ref.shape[1]))
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    @pl.when(j == nb - 1)
-    def _flush():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+    def init(b):
+        if not fused:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, _NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            return
+        # the newest token (index length-1) is not in the pool: the
+        # online softmax starts from it, its K/V rows picked per head
+        pick = (jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (hq, rt), 0),
+                            gh)
+                == jax.lax.broadcasted_iota(jnp.int32, (hq, rt), 1))
+        s_all = jax.lax.dot_general(q_ref[b], kn_ref[b],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        s_new = jnp.sum(jnp.where(pick, s_all, 0.0), axis=-1,
+                        keepdims=True) * scale        # (Hq, 1)
+        vn = vn_ref[b]
+        acc_ref[...] = jax.lax.dot_general(
+            pick.astype(vn.dtype), vn, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(s_new, m_ref.shape)
+        l_ref[...] = jnp.ones_like(l_ref)
+
+    # nxt[b]: the first slot after b with pool pages to copy (nslots where
+    # there is none)
+    def link(i, after):
+        b = nslots - 1 - i
+        nxt_ref[b] = after
+        return jnp.where(span(b)[3] > 0, b, after)
+
+    first = jax.lax.fori_loop(0, nslots, link, jnp.int32(nslots))
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(first < nslots)
+    def _prime():
+        start(first, 0, 0)
+
+    def slot_body(b, cur):
+        live = len_ref[b] > 0
+        nblk = span(b)[3]
+
+        @pl.when(live)
+        def _init():
+            init(b)
+
+        def block(j, cur):
+            more = j + 1 < nblk
+            b_next = jnp.where(more, b, nxt_ref[b])
+
+            @pl.when(b_next < nslots)
+            def _prefetch():
+                start(b_next, jnp.where(more, j + 1, 0), 1 - cur)
+
+            wait(b, j, cur)
+            compute(b, j, cur)
+            return 1 - cur
+
+        cur = jax.lax.fori_loop(0, nblk, block, cur)
+
+        @pl.when(live)
+        def _flush():
+            o_ref[b] = (acc_ref[...]
+                        / jnp.maximum(l_ref[:, :1], 1e-30)).astype(
+                            o_ref.dtype)
+
+        return cur
+
+    jax.lax.fori_loop(0, nslots, slot_body, jnp.int32(0))
 
 
 def _paged_call(q, new_kv, k_pages, v_pages, lengths, block_tables, *,
@@ -200,38 +340,54 @@ def _paged_call(q, new_kv, k_pages, v_pages, lengths, block_tables, *,
     """Shared pallas_call of the paged and fused kernels. ``new_kv`` is
     ``(k_new, v_new)`` (B, Hkv, 1, hd) for the fused step, else None."""
     B, Hq, _, hd = q.shape
-    _, ps, Hkv, _ = k_pages.shape
+    P, ps, Hkv, _ = k_pages.shape
+    G = Hq // Hkv
     nb = block_tables.shape[1]
+    hpr = _heads_per_row(Hkv, hd)
+    rt, lanes = Hkv // hpr, hpr * hd
     fused = new_kv is not None
-    head_spec = lambda h: pl.BlockSpec((1, h, hd),
-                                       lambda b, j, lens, bt: (b, 0, 0))
-    page_spec = pl.BlockSpec((1, ps, Hkv, hd),
-                             lambda b, j, lens, bt: (bt[b, j], 0, 0, 0))
-    in_specs = [head_spec(Hq)]
-    operands = [q[:, :, 0]]
+    q = q[:, :, 0]
+    if hpr > 1:
+        # each query row holds its head's hd lanes of the row, zeros in
+        # the lanes of the heads that share the row
+        slot = jax.nn.one_hot(jnp.arange(Hq) // G % hpr, hpr, dtype=q.dtype)
+        q = (q[:, :, None, :] * slot[None, :, :, None]).reshape(B, Hq, lanes)
+    whole = lambda h: pl.BlockSpec((B, h, lanes),
+                                   lambda i, lens, bt: (0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [whole(Hq)]
+    operands = [q]
     if fused:
-        in_specs += [head_spec(Hkv), head_spec(Hkv)]
-        operands += [x[:, :, 0] for x in new_kv]
-    in_specs += [page_spec, page_spec]
-    operands += [k_pages, v_pages]
+        in_specs += [whole(rt), whole(rt)]
+        operands += [x.reshape(B, rt, lanes) for x in new_kv]
+    in_specs += [pool, pool]
+    operands += [x.reshape(P, ps, rt, lanes) for x in (k_pages, v_pages)]
+    ppb = _pages_per_block(ps * Hkv * hd * k_pages.dtype.itemsize, nb)
     kernel = functools.partial(_paged_kernel, scale=hd ** -0.5, ps=ps,
-                               nb=nb, window=window, hkv=Hkv, g=Hq // Hkv,
-                               fused=fused)
+                               nb=nb, ppb=ppb, rt=rt, gh=G * hpr,
+                               window=window, fused=fused)
+    buf = pltpu.VMEM((2, ppb * ps * rt, lanes), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, nb),
+        grid=(1,),
         in_specs=in_specs,
-        out_specs=head_spec(Hq),
-        scratch_shapes=[pltpu.VMEM((Hq, hd), jnp.float32),
+        out_specs=whole(Hq),
+        scratch_shapes=[buf, buf,
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((B,), jnp.int32),
+                        pltpu.VMEM((Hq, lanes), jnp.float32),
                         pltpu.VMEM((Hq, 128), jnp.float32),
                         pltpu.VMEM((Hq, 128), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, lanes), q.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), *operands)
+    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32).reshape(-1),
+      *operands)
+    if hpr > 1:
+        out = (out.reshape(B, Hq, hpr, hd) * slot[None, :, :, None]).sum(2)
     return out[:, :, None]
 
 

@@ -156,29 +156,70 @@ def test_decode_attention(C, Hq, Hkv, pos, window):
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("window", [0, 5])
-def test_paged_decode_attention_matches_ref(window):
-    """Paged kernel vs its gather oracle over a scattered (permuted)
-    page pool, including a dead slot (length 0 → zeros)."""
-    from repro.kernels.decode_attention.ops import decode_attention_op
-    from repro.kernels.decode_attention.ref import paged_decode_attention_ref
-    ks = jax.random.split(KEY, 3)
-    B, Hq, Hkv, hd, ps, nb = 3, 4, 2, 32, 8, 4
+# Paged cases: (B, Hq, Hkv, hd, ps, nb, lengths). "small" is the first
+# shape; "g2-hd128" has 32 pages per compute block (f32 pages of 32 KiB)
+# over a 40-block table, so its blocks do not divide the table, and lengths
+# 0, 1, ps-1, ps, ps+1, one inside the first block, one inside the second
+# and the full table; "g1-hd64" packs two 64-wide heads per 128-lane row.
+_PAGED_SHAPES = {
+    "small": (3, 4, 2, 32, 8, 4, [13, 0, 32]),
+    "g2-hd128": (8, 8, 4, 128, 16, 40, [0, 1, 15, 16, 17, 300, 520, 640]),
+    "g1-hd64": (8, 4, 4, 64, 16, 40, [0, 1, 15, 16, 17, 300, 520, 640]),
+}
+_PAGED_CASES = [
+    pytest.param(0, "small", id="0"),
+    pytest.param(5, "small", id="5"),
+    *(pytest.param(w, shape, id=f"{shape}-w{w}")
+      for shape in ("g2-hd128", "g1-hd64") for w in (0, 203)),
+]
+
+
+def _paged_case(key, shape, window, *, seed, fused=False):
+    """q, a scattered page pool (clean, and with NaN in every row that no
+    valid token reads: partial pages' tails, rows before the window, pages
+    only padded table entries point to and, fused, the newest token's row),
+    block tables and lengths. Fused lengths count the newest token."""
+    B, Hq, Hkv, hd, ps, nb, lens = _PAGED_SHAPES[shape]
+    if fused and shape == "small":
+        lens = [14, 0, 32]
     P = B * nb + 2
+    ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, hd), jnp.float32)
     kp = jax.random.normal(ks[1], (P, ps, Hkv, hd), jnp.float32)
     vp = jax.random.normal(ks[2], (P, ps, Hkv, hd), jnp.float32)
-    perm = np.random.default_rng(0).permutation(P)[:B * nb]
-    bt = jnp.asarray(perm.reshape(B, nb).astype(np.int32))
-    lens = jnp.asarray(np.array([13, 0, 32], np.int32))
-    got = decode_attention_op(q, kp, vp, lens, window=window,
+    bt = np.random.default_rng(seed).permutation(P)[:B * nb].reshape(B, nb)
+    read = np.zeros((P, ps), bool)
+    for b, n in enumerate(lens):
+        lo = max(n - window, 0) if window else 0
+        for t in range(lo, n - fused):
+            read[bt[b, t // ps], t % ps] = True
+    poison = lambda x: jnp.where(jnp.asarray(read)[..., None, None], x,
+                                 jnp.nan)
+    return (q, (kp, vp), (poison(kp), poison(vp)),
+            jnp.asarray(bt.astype(np.int32)),
+            jnp.asarray(np.array(lens, np.int32)))
+
+
+@pytest.mark.parametrize("window,shape", _PAGED_CASES)
+def test_paged_decode_attention_matches_ref(window, shape):
+    """Paged kernel vs its gather oracle over a scattered (permuted)
+    page pool, including a dead slot (length 0 → zeros). The kernel reads
+    a pool with NaN in every row no valid token reads; its output must be
+    finite and match the oracle's over the clean pool."""
+    from repro.kernels.decode_attention.ops import decode_attention_op
+    from repro.kernels.decode_attention.ref import paged_decode_attention_ref
+    q, (kp, vp), (kx, vx), bt, lens = _paged_case(KEY, shape, window,
+                                                  seed=0)
+    got = decode_attention_op(q, kx, vx, lens, window=window,
                               block_tables=bt)
     want = paged_decode_attention_ref(
         q.transpose(0, 2, 1, 3), kp, vp, lens, bt,
         window=window).transpose(0, 2, 1, 3)
+    assert bool(jnp.all(jnp.isfinite(got)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
-    assert bool(jnp.all(got[1] == 0))          # dead slot stays zero
+    for b in np.flatnonzero(np.asarray(lens) == 0):
+        assert bool(jnp.all(got[b] == 0))      # dead slot stays zero
 
 
 def test_paged_matches_contiguous_decode_attention():
@@ -202,29 +243,26 @@ def test_paged_matches_contiguous_decode_attention():
                                atol=1e-3, rtol=1e-3)
 
 
-@pytest.mark.parametrize("window", [0, 6])
-def test_fused_decode_matches_scatter_then_paged(window):
+@pytest.mark.parametrize("window,shape", [
+    pytest.param(0, "small", id="0"), pytest.param(6, "small", id="6"),
+    *_PAGED_CASES[2:]])
+def test_fused_decode_matches_scatter_then_paged(window, shape):
     """The fused serving step (new-token K/V substituted in-register)
     must match scatter-then-paged-attention ≤ 1e-3, and the XLA
     fallback must agree on the *same* inputs. Includes a dead slot
-    (length 0 → zeros)."""
+    (length 0 → zeros). The fused kernel reads a pool with NaN in every
+    row no valid token reads, the newest token's row among them."""
     from repro.kernels.decode_attention.ops import (
         decode_attention_op, fused_decode_step_op,
         fused_paged_attention_xla)
-    ks = jax.random.split(KEY, 5)
-    B, Hq, Hkv, hd, ps, nb = 3, 4, 2, 32, 8, 4
-    P = B * nb + 2
-    q = jax.random.normal(ks[0], (B, 1, Hq, hd), jnp.float32)
-    kn = jax.random.normal(ks[1], (B, 1, Hkv, hd), jnp.float32)
-    vn = jax.random.normal(ks[2], (B, 1, Hkv, hd), jnp.float32)
-    kp = jax.random.normal(ks[3], (P, ps, Hkv, hd), jnp.float32)
-    vp = jax.random.normal(ks[4], (P, ps, Hkv, hd), jnp.float32)
-    perm = np.random.default_rng(1).permutation(P)[:B * nb]
-    bt = jnp.asarray(perm.reshape(B, nb).astype(np.int32))
-    # lengths INCLUDE the new token; slot 1 is dead
-    lens = jnp.asarray(np.array([14, 0, 32], np.int32))
+    q, (kp, vp), (kx, vx), bt, lens = _paged_case(
+        jax.random.fold_in(KEY, 1), shape, window, seed=1, fused=True)
+    B, (Hkv, hd) = q.shape[0], kp.shape[2:]
+    ks = jax.random.split(jax.random.fold_in(KEY, 2))
+    kn = jax.random.normal(ks[0], (B, 1, Hkv, hd), jnp.float32)
+    vn = jax.random.normal(ks[1], (B, 1, Hkv, hd), jnp.float32)
 
-    fused = fused_decode_step_op(q, kn, vn, kp, vp, lens, bt,
+    fused = fused_decode_step_op(q, kn, vn, kx, vx, lens, bt,
                                  window=window)
     # the XLA fallback speaks kernel layout (B,H,1,hd)
     xla = fused_paged_attention_xla(
@@ -233,8 +271,9 @@ def test_fused_decode_matches_scatter_then_paged(window):
         window=window).transpose(0, 2, 1, 3)
 
     # oracle: scatter the new token into the pool, then plain paged
-    kp2, vp2 = np.asarray(kp).copy(), np.asarray(vp).copy()
-    for b, L in enumerate([14, 0, 32]):
+    kp2, vp2 = np.array(kp), np.array(vp)
+    ps = kp.shape[1]
+    for b, L in enumerate(np.asarray(lens)):
         if L == 0:
             continue
         pg, off = int(bt[b, (L - 1) // ps]), (L - 1) % ps
@@ -242,12 +281,14 @@ def test_fused_decode_matches_scatter_then_paged(window):
         vp2[pg, off] = np.asarray(vn)[b, 0]
     want = decode_attention_op(q, jnp.asarray(kp2), jnp.asarray(vp2),
                                lens, window=window, block_tables=bt)
+    assert bool(jnp.all(jnp.isfinite(fused)))
     np.testing.assert_allclose(np.asarray(fused), np.asarray(want),
                                atol=1e-3, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(xla), np.asarray(want),
                                atol=1e-3, rtol=1e-3)
-    assert bool(jnp.all(fused[1] == 0))        # dead slot stays zero
-    assert bool(jnp.all(xla[1] == 0))
+    for b in np.flatnonzero(np.asarray(lens) == 0):
+        assert bool(jnp.all(fused[b] == 0))    # dead slot stays zero
+        assert bool(jnp.all(xla[b] == 0))
 
 
 def test_fused_decode_new_token_only():

@@ -79,6 +79,21 @@ def _paged(kind):
             ((B, hq, 1, hd), bf), pool, pool) + tail
 
 
+def _internlm2_fused(b, nb):
+    """The benchmark's InternLM2 decode attention: bf16 pools of 1,536
+    pages × 16 × 8 × 128, ``b`` slots of ``nb`` blocks (chat 12 × 128,
+    docs 3 × 512); its double-buffered page blocks must fit in VMEM."""
+    from repro.kernels.decode_attention.decode_attention import (
+        fused_paged_decode_attention)
+    cfg = get_config("internlm2-1.8b")
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool, kv = ((1536, PAGE, hkv, hd), bf), ((b, hkv, 1, hd), bf)
+    return (functools.partial(fused_paged_decode_attention, interpret=False),
+            ((b, hq, 1, hd), bf), kv, kv, pool, pool, ((b,), i32),
+            ((b, nb), i32))
+
+
 def _sample():
     from repro.kernels.decode_attention.decode_attention import (
         sample_tokens)
@@ -117,6 +132,8 @@ def _rwkv():
 KERNELS = {
     "paged_decode_attention": lambda: _paged("paged"),
     "fused_paged_decode_attention": lambda: _paged("fused"),
+    "fused_paged_decode_attention.chat": lambda: _internlm2_fused(12, 128),
+    "fused_paged_decode_attention.docs": lambda: _internlm2_fused(3, 512),
     "sample_tokens": _sample,
     "flash_attention": _flash,
     "rglru_scan": _rglru,
