@@ -13,7 +13,6 @@ from __future__ import annotations
 import argparse
 import contextlib
 import gc
-import importlib.util
 import json
 import math
 import shutil
@@ -25,7 +24,9 @@ from typing import List, Optional
 
 import numpy as np
 
-BENCH = Path(__file__).resolve().parent
+from bench import found
+
+BENCH = found.BENCH
 ROOT = BENCH.parent
 RUNS = ROOT / ".bench_runs"          # traces; listed in .gitignore
 #: JAX's persistent compilation cache, at a fixed path in the checkout
@@ -35,6 +36,10 @@ CACHE = ROOT / ".jax_cache"
 
 class NoChip(RuntimeError):
     """JAX found no TPU, or fewer chips than the cell asks for."""
+
+
+class NoTrace(RuntimeError):
+    """A traced run's traffic ended before its traced tail began."""
 
 
 # ---------------------------------------------------------------------------
@@ -74,14 +79,7 @@ def units(manifest: dict) -> dict:
 
 def load_metric(name: str, bench: Path = BENCH):
     """The reader module ``metrics/<name>.py`` (names may hold dots)."""
-    path = bench / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise FileNotFoundError(f"no metric reader {path}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return found.module("metrics", name, bench)
 
 
 def load_peaks(kind: str, bench: Path = BENCH) -> dict:
@@ -451,7 +449,7 @@ def logit_gaps(params, cfg_data: dict, sample, controls=False,
     import jax.numpy as jnp
     from bench import reference
 
-    mod = reference.load(cfg_data["reference"])
+    mod = reference.load(cfg_data["reference"], cfg_data["bench"])
     fns = {"ref": reference.dot_f32, "ctl": reference.dot_fp8}
     jits = {k: jax.jit(lambda w, t, p, dot=dot: mod.logits(
         w, cfg_data, t, p, dot)) for k, dot in fns.items()}
@@ -517,7 +515,7 @@ def run_cell(args, t_start: float, manifest=None, require_tpu=True,
     result's ``control`` key (for the limit's upper reading; the
     benchmark's own runs do not)."""
     import jax
-    from bench import configs, traffic, weights
+    from bench import configs, reference, traffic, weights
     from repro.models import build_model
 
     wl = workload or load_workload(args.workload, bench)
@@ -535,10 +533,11 @@ def run_cell(args, t_start: float, manifest=None, require_tpu=True,
     devs = require_chips(wl["chips"]) if require_tpu else jax.devices()
     counter = CompileCounter()
 
-    data = configs.load(wl["config"], bench / "configs")
+    data = configs.load(wl["config"], bench)
     cfg = configs.model_config(data)
     model = build_model(cfg)
-    params = weights.make(model, args.seed)
+    family = reference.load(data["reference"], bench)
+    params = weights.make(model, args.seed, rules=weights.joined(family))
     jax.block_until_ready(params)
 
     rec = Recorder()
@@ -580,6 +579,12 @@ def run_cell(args, t_start: float, manifest=None, require_tpu=True,
 
     out = serve(engine, params, requests, win, rec,
                 on_trace=on_trace if args.trace else None)
+    if args.trace and out["traced"] is None:
+        if vmm is not None:
+            vmm.shutdown()
+        raise NoTrace(f"the traffic ended at {out['end']:.2f} s, before the "
+                      f"traced tail was to begin at {win.host_end:.2f} s: "
+                      f"this run has no trace of its own")
     compiles = counter.total() - compiles0
     due = in_window(requests, win.preroll, win.end)
     late = [r.submitted - r.due for r in requests if not math.isnan(r.submitted)]
@@ -707,6 +712,9 @@ def main(argv, t_start: float) -> int:
     except NoChip as exc:
         print(f"[bench] {exc}", file=sys.stderr)
         return 2
+    except NoTrace as exc:
+        print(f"[bench] {exc}", file=sys.stderr)
+        return 3
     for name, c in result["checks"].items():
         print(f"check {name}: {c['value']} (limit {c['limit']})",
               file=sys.stderr)

@@ -6,17 +6,28 @@ logits at ``positions_out`` of one sequence ``tokens``, computed from
 the weights ``w`` in straightforward ``jax.numpy``. They import nothing
 of the program under test. ``dot`` is the matrix product to use:
 ``dot_f32`` for the reference, a lower-precision one for the control.
+
+A family module may also declare what the rest of the benchmark needs
+to know of the family:
+
+- ``WEIGHT_RULES``, ``WEIGHT_RANK``: rules for the leaves of its weights
+  that ``weights.RULES`` lacks (``weights.joined``);
+- ``REFERENCE_ONLY``: keys of its configuration files that the reference
+  and the operation counts read and the program takes no setting for
+  (``configs.model_config`` leaves them out).
 """
 from __future__ import annotations
-
-import importlib
 
 import jax
 import jax.numpy as jnp
 
+from bench import found
 
-def load(family: str):
-    return importlib.import_module(f"bench.reference.{family}")
+
+def load(family: str, bench=found.BENCH):
+    """The module ``reference/<family>.py`` of benchmark directory
+    ``bench``."""
+    return found.module("reference", family, bench)
 
 
 def dot_f32(a, b):

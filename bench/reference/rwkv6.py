@@ -15,6 +15,22 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+#: sizes the reference and the operation counts read, which the program
+#: fixes in code and takes no setting for
+REFERENCE_ONLY = ("rwkv_mix_lora_rank", "rwkv_decay_lora_rank")
+
+#: the time-mix and channel-mix leaves (see ``weights.RULES``)
+WEIGHT_RULES = {
+    "w_r": ("fan_in", 1), "w_k": ("fan_in", 1), "w_v": ("fan_in", 1),
+    "w_g": ("fan_in", 1), "w_o": ("fan_in", 1),
+    "mix_A": ("fan_in", 1), "mix_B": ("normal", 0.02),
+    "decay_A": ("fan_in", 1), "decay_B": ("fan_in", 1),
+    "mu_base": ("const", 0.5), "mu_k": ("const", 0.5),
+    "mu_r": ("const", 0.5), "mu_rkvwg": ("around", 0.5),
+    "decay_base": ("uniform", (-7.0, 1.0)), "bonus_u": ("normal", 0.02),
+}
+WEIGHT_RANK = {"mix_B": 3, "mu_rkvwg": 2, "bonus_u": 2}
+
 
 def layernorm(x, scale, bias, eps):
     mu = x.mean(-1, keepdims=True)

@@ -3,6 +3,7 @@ chip skipped: sound runs come out correct, and each fault the serving
 path can have, planted underneath the timed path, comes out not correct.
 """
 import argparse
+import copy
 import time
 
 import jax
@@ -21,12 +22,13 @@ def bench(tmp_path_factory):
     return bench_dir(tmp_path_factory.mktemp("bench"), CELLS)
 
 
-def run(bench, name, trace=0, fault=None, control=False, seed=SEED):
-    args = argparse.Namespace(workload=name, seed=seed, seconds=3,
+def run(bench, name, trace=0, fault=None, control=False, seed=SEED,
+        seconds=3, workload=None):
+    args = argparse.Namespace(workload=name, seed=seed, seconds=seconds,
                               trace=trace)
     return harness.run_cell(args, time.perf_counter(), require_tpu=False,
                             bench=bench, fault=fault, control=control,
-                            cache=False)
+                            cache=False, workload=workload)
 
 
 @pytest.mark.parametrize("name,trace", [("internlm2.chat", 0),
@@ -43,6 +45,16 @@ def test_sound_run_is_correct_and_reports_its_metrics(bench, name, trace):
         assert res["metrics"]["setup_s"]["value"] > 0
     else:
         assert res["device"]["window_s"] > 0 and "breakdown" in res
+
+
+def test_traced_run_whose_traffic_ends_before_the_tail_fails(bench):
+    """One request, due at 0 s and served in well under the 5 s before
+    the traced tail: the run has no trace of its own and says so."""
+    wl = copy.deepcopy(harness.load_workload("internlm2.chat", bench))
+    wl["traffic"]["arrivals"]["rate_per_s"] = 0.2
+    wl["traffic"]["preroll_s"] = 5
+    with pytest.raises(harness.NoTrace, match="before the traced tail"):
+        run(bench, "internlm2.chat", trace=1, seconds=1, workload=wl)
 
 
 def _state_unchanged(attr):

@@ -11,11 +11,18 @@ from bench.tests.tiny import SIZES, config
 from repro.models import build_model
 
 
+def seeded(data, seed):
+    """The program's model of configuration ``data`` and its weights
+    drawn from ``seed`` by its family's rules."""
+    model = build_model(configs.model_config(data))
+    rules = weights.joined(reference.load(data["reference"]))
+    return model, weights.make(model, seed, rules=rules)
+
+
 @pytest.mark.parametrize("name", sorted(SIZES))
 def test_reference_matches_program_prefill_logits(name):
     data = config(name, compute_dtype="float32")
-    model = build_model(configs.model_config(data))
-    w = weights.make(model, 2 ** 31 + 77)
+    model, w = seeded(data, 2 ** 31 + 77)
     toks = np.random.default_rng(0).integers(0, data["vocab"], 40)
     toks = jnp.asarray(toks, jnp.int32)
     with jax.default_matmul_precision("highest"):
@@ -31,8 +38,7 @@ def test_reference_matches_program_prefill_logits(name):
 @pytest.mark.parametrize("name", sorted(SIZES))
 def test_float8_control_departs_from_the_reference(name):
     data = config(name)
-    model = build_model(configs.model_config(data))
-    w = weights.make(model, 5)
+    _, w = seeded(data, 5)
     toks = jnp.asarray(np.arange(32) % data["vocab"], jnp.int32)
     mod = reference.load(data["reference"])
     ref = np.asarray(mod.logits(w, data, toks, jnp.arange(32),
@@ -44,10 +50,9 @@ def test_float8_control_departs_from_the_reference(name):
 
 def test_weights_repeat_from_the_seed():
     data = config("rwkv6-1.6b")
-    model = build_model(configs.model_config(data))
-    a = weights.make(model, 2 ** 31 + 3)
-    b = weights.make(model, 2 ** 31 + 3)
-    c = weights.make(model, 3)
+    _, a = seeded(data, 2 ** 31 + 3)
+    _, b = seeded(data, 2 ** 31 + 3)
+    _, c = seeded(data, 3)
     same = jax.tree.map(lambda x, y: bool((x == y).all()), a, b)
     assert all(jax.tree.leaves(same))
     assert not np.array_equal(a["lm_head"], c["lm_head"])

@@ -1,24 +1,28 @@
 """Small versions of the benchmark's cells for the CPU tests: every
 width cut, the same code paths. They live in a benchmark directory of
 their own, written under a test's temporary path, so no committed cell
-or configuration carries a size for tests."""
+or configuration carries a size for tests.
+
+The small sizes of configuration ``<name>`` are ``sizes/<name>.json``
+here, found by name: the keys they set over the configuration's file,
+a nested group's keys over that group's."""
 import copy
 import json
 import shutil
+from pathlib import Path
 
 from bench import configs, harness
 
-SIZES = {
-    "internlm2-1.8b": dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
-                           d_head=16, d_ff=128, vocab=512),
-    "rwkv6-1.6b": dict(n_layers=2, d_model=128, n_heads=2, n_kv_heads=2,
-                       rwkv_head_dim=64, d_ff=256, vocab=512),
-}
+SIZES = {p.stem: json.loads(p.read_text())
+         for p in sorted((Path(__file__).parent / "sizes").glob("*.json"))}
 
 
 def config(name: str, **extra) -> dict:
     """Configuration ``name`` at the tests' small sizes."""
-    return {**configs.load(name), **SIZES[name], **extra}
+    data = configs.load(name)
+    for k, v in {**SIZES[name], **extra}.items():
+        data[k] = {**data.get(k, {}), **v} if isinstance(v, dict) else v
+    return data
 
 
 def workload(name: str) -> dict:
@@ -39,8 +43,11 @@ def workload(name: str) -> dict:
 
 def bench_dir(root, cells):
     """A benchmark directory under ``root`` holding ``cells`` and their
-    configurations at small sizes, and the committed metric readers."""
-    shutil.copytree(harness.BENCH / "metrics", root / "metrics")
+    configurations at small sizes, and the committed metric readers and
+    family modules."""
+    for kind in ("metrics", "reference", "counts"):
+        shutil.copytree(harness.BENCH / kind, root / kind,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (root / "configs").mkdir()
     (root / "workloads").mkdir()
     for cell in cells:
